@@ -130,6 +130,23 @@ def call_name(node: ast.Call) -> str:
     return dotted(node.func)
 
 
+_SHAPE_FNS = {"jnp.zeros", "jnp.ones", "jnp.full", "jnp.empty",
+              "jnp.arange", "jnp.broadcast_to", "jax.ShapeDtypeStruct",
+              "np.zeros", "np.ones", "np.full", "np.empty"}
+# the argument that is the shape: the first, but the second of broadcast_to
+_SHAPE_ARG = {"jnp.broadcast_to": 1}
+
+
+def shape_arg(node: ast.Call) -> ast.AST | None:
+    """The argument a shape-taking call (``jnp.zeros``, ...) reads as its
+    shape, or None when ``node`` is no such call or omits it."""
+    name = call_name(node)
+    k = _SHAPE_ARG.get(name, 0)
+    if name in _SHAPE_FNS and len(node.args) > k:
+        return node.args[k]
+    return None
+
+
 def iter_functions(tree: ast.AST):
     """Every FunctionDef/AsyncFunctionDef in the tree (methods included,
     nested included)."""

@@ -13,11 +13,9 @@ from __future__ import annotations
 
 import ast
 
-from ..core import Rule, SourceModule, call_name, jitted_functions
+from ..core import (Rule, SourceModule, call_name, jitted_functions,
+                    shape_arg)
 
-_SHAPE_FNS = {"jnp.zeros", "jnp.ones", "jnp.full", "jnp.empty",
-              "jnp.arange", "jnp.broadcast_to", "jax.ShapeDtypeStruct",
-              "np.zeros", "np.ones", "np.full", "np.empty"}
 _UNHASHABLE = (ast.List, ast.Dict, ast.Set, ast.ListComp, ast.DictComp,
                ast.SetComp)
 
@@ -68,8 +66,9 @@ class JitStaticDisciplineRule(Rule):
         for node in ast.walk(fn):
             if isinstance(node, ast.Call):
                 name = call_name(node)
-                if name in _SHAPE_FNS and node.args:
-                    used = _names_in(node.args[0]) & dynamic
+                shape = shape_arg(node)
+                if shape is not None:
+                    used = _names_in(shape) & dynamic
                     for p in sorted(used):
                         yield mod.finding(
                             self.name, node,

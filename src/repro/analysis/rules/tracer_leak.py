@@ -15,13 +15,10 @@ from __future__ import annotations
 import ast
 
 from ..core import (Rule, SourceModule, call_name, fn_param_names,
-                    jitted_functions)
+                    jitted_functions, shape_arg)
 
 _SANITIZE_ATTRS = {"shape", "ndim", "dtype", "size", "aval", "weak_type"}
 _SANITIZE_CALLS = {"len", "isinstance", "type", "hasattr", "getattr"}
-_SHAPE_FNS = {"jnp.zeros", "jnp.ones", "jnp.full", "jnp.empty",
-              "jnp.arange", "jnp.broadcast_to", "jax.ShapeDtypeStruct",
-              "np.zeros", "np.ones", "np.full", "np.empty"}
 
 
 def _expr_tainted(node: ast.AST, tainted: set[str]) -> bool:
@@ -67,9 +64,10 @@ class TracerLeakRule(Rule):
 
         def shape_uses(expr: ast.AST):
             for node in ast.walk(expr):
-                if (isinstance(node, ast.Call)
-                        and call_name(node) in _SHAPE_FNS and node.args
-                        and _expr_tainted(node.args[0], tainted)):
+                if not isinstance(node, ast.Call):
+                    continue
+                shape = shape_arg(node)
+                if shape is not None and _expr_tainted(shape, tainted):
                     found.append(mod.finding(
                         self.name, node,
                         f"traced value used as a shape in jitted "

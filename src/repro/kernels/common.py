@@ -18,7 +18,6 @@ deployment target.
 
 from __future__ import annotations
 
-import os
 from typing import Callable
 
 import jax
@@ -30,9 +29,8 @@ from ..core.qtensor import QTensor
 
 
 def _interpret_default() -> bool:
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env == "1"
+    """Interpret the Pallas kernels exactly where Mosaic cannot compile
+    them: on a CPU backend."""
     return jax.default_backend() == "cpu"
 
 

@@ -45,10 +45,14 @@ step scores all C chunk queries against one page tile, with a per-row
 ``(C, P)`` validity mask (written ∧ causal ∧ ``logical_idx <= qpos`` —
 the logical-index term keeps stale rows beyond a lane's frontier out even
 when their stored positions look plausible).  This closes the last dense
-dequant: packed pages stay packed end to end, and because the page
-enumeration order is independent of the chunk split, serve output is
-bitwise identical across ``--prefill-chunk`` values for quantized
-full-table layers (ring layers keep the gather path).
+dequant: packed pages stay packed end to end, and the page enumeration
+order is independent of the chunk split, so every chunk split attends the
+same stored rows in the same order.  The Pallas kernel's outputs are then
+bitwise chunk-size invariant; the XLA twin's, and the model's projections
+around either, are not: XLA blocks a dot or a reduction by its shape, so
+splits differ in the last bits (tests/test_kv_dynamic.py holds the decode
+logits after chunked prefill to a stated tolerance).  Ring layers keep
+the gather path.
 
 ``active_pages`` bounds the page loop: the serving engine knows the
 largest live horizon across its lanes each iteration and passes a bucketed
@@ -96,11 +100,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-try:  # moved to the jax namespace in newer releases
-    from jax.experimental.shard_map import shard_map
-except ImportError:  # pragma: no cover
-    from jax import shard_map
-
 from .common import _interpret_default
 
 NEG_INF = -2.0e38
@@ -139,13 +138,48 @@ def _lane_bound(lane_pages: jax.Array | None, b: int, nj: int) -> jax.Array:
     return jnp.clip(lane_pages.astype(jnp.int32), 1, nj)
 
 
+# Pallas TPU blocks must match the (8, 128) tiling in their last two dims
+# or span them whole, so a per-token (num_pages, P) leaf cannot be tiled
+# one page at a time.  The kernels view it with a unit axis instead: a
+# (1, P) row where it masks score columns (positions), a (P, 1) column
+# where it scales tile rows (MLA dequant scales).
+
+def _row_leaf(x: jax.Array) -> jax.Array:
+    return x.reshape(x.shape[0], 1, x.shape[1])
+
+
+def _col_leaf(x: jax.Array) -> jax.Array:
+    return x.reshape(*x.shape, 1)
+
+
+def _key_index(tp: int) -> jax.Array:
+    """(1, P) logical key indices of the page tile at grid step
+    ``(slot, j)``."""
+    return (pl.program_id(1) * tp
+            + jax.lax.broadcasted_iota(jnp.int32, (1, tp), 1))
+
+
+_MAX_ROWS = 512        # query rows per chunked-prefill grid step
+
+
+def _row_block(rows: int, cap: int) -> int:
+    """Largest divisor of ``rows`` that is at most ``cap`` and a multiple
+    of 8 (the sublane tiling), or ``rows`` itself when small."""
+    if rows <= cap:
+        return rows
+    for rb in range(cap - cap % 8, 7, -8):
+        if rows % rb == 0:
+            return rb
+    return rows
+
+
 def _finish(o_ref, acc_ref, l_ref, nj: int):
     """Write the normalised accumulator on the last page step."""
 
     @pl.when(pl.program_id(1) == nj - 1)
     def _():
         l = jnp.maximum(l_ref[:, 0:1], 1e-30)
-        o_ref[0] = acc_ref[...] / l
+        o_ref[0] = (acc_ref[...] / l).reshape(o_ref.shape[1:])
 
 
 def _online_update(s, valid, v_tile, m_ref, l_ref, acc_ref):
@@ -222,9 +256,16 @@ def _dequant(qs: jax.Array, d: jax.Array, mode: str) -> jax.Array:
     in-kernel tile loader *and* the bounded-gather dequant, so the two
     impls see bit-identical f32 values.
     """
+    return _dequant_rows(qs, d[..., None], mode)
+
+
+def _dequant_rows(qs: jax.Array, d_col: jax.Array, mode: str) -> jax.Array:
+    """:func:`_dequant` with the scales already carrying the trailing unit
+    axis (``d_col``: (..., 1)) — the MLA kernels load their per-token
+    scales as a (P, 1) column and so broadcast them with no shape cast."""
     if mode == "q4_0":
-        qs = unpack_q4_rows(qs)
-    return qs.astype(jnp.float32) * d.astype(jnp.float32)[..., None]
+        qs = _q4_values(qs)
+    return qs.astype(jnp.float32) * d_col.astype(jnp.float32)
 
 
 def _gathered_kv(kv: tuple, btj: jax.Array, quant):
@@ -342,7 +383,7 @@ def _attn_core(q, kv, pos_pool, block_table, pos, lane_pages, *,
                 preferred_element_type=jnp.float32).reshape(h, tp)
             if softcap:
                 s = softcap * jnp.tanh(s / softcap)
-            pt = pp_ref[0]                                   # (P,) int32
+            pt = pp_ref[0]                                   # (1, P) int32
             pb = pos_ref[pl.program_id(0)]
             valid = (pt >= 0) & (pt <= pb)
             if window:
@@ -350,7 +391,7 @@ def _attn_core(q, kv, pos_pool, block_table, pos, lane_pages, *,
             # clamped trailing steps revisit the lane's last (live!) page:
             # mask them out so its keys are not folded in twice
             valid &= pl.program_id(1) < lp_ref[pl.program_id(0)]
-            s = jnp.where(valid[None, :], s, NEG_INF)
+            s = jnp.where(valid, s, NEG_INF)
 
             def v_tile(p):
                 p3 = p.reshape(hkv, rep, tp)
@@ -387,9 +428,7 @@ def _attn_core(q, kv, pos_pool, block_table, pos, lane_pages, *,
             in_specs=[
                 pl.BlockSpec((1, h, d), lambda i, j, bt, ps, lp: (i, 0, 0)),
                 *kv_specs,
-                pl.BlockSpec((1, tp),
-                             lambda i, j, bt, ps, lp: (pj(i, j, bt, ps, lp),
-                                                       0)),
+                pl.BlockSpec((1, 1, tp), page3),
             ],
             out_specs=pl.BlockSpec((1, h, dv),
                                    lambda i, j, bt, ps, lp: (i, 0, 0)),
@@ -404,7 +443,7 @@ def _attn_core(q, kv, pos_pool, block_table, pos, lane_pages, *,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((b, h, dv), jnp.float32),
             interpret=interpret,
-        )(block_table, pos, lane_pages, q, *kv_ops, pos_pool)
+        )(block_table, pos, lane_pages, q, *kv_ops, _row_leaf(pos_pool))
 
     args = (block_table, pos, lane_pages, q, *kv, pos_pool)
     if mesh is None:
@@ -427,8 +466,8 @@ def _attn_core(q, kv, pos_pool, block_table, pos, lane_pages, *,
         # bitwise identical to the single-device call
         in_specs = tuple(PS() for _ in args)
         out_specs = PS()
-    return shard_map(shard_run, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)(*args)
+    return jax.shard_map(shard_run, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)(*args)
 
 
 # ---------------------------------------------------------------------------
@@ -569,8 +608,8 @@ def _mla_core(q_eff, q_rope, kv, block_table, pos, lane_pages, *,
             _init_accumulators(m_ref, l_ref, acc_ref)
             if quant:
                 cq_ref, cd_ref, kq_ref, kd_ref = kv_refs
-                ckv = _dequant(cq_ref[0], cd_ref[0], quant[0])
-                krope = _dequant(kq_ref[0], kd_ref[0], quant[1])
+                ckv = _dequant_rows(cq_ref[0], cd_ref[0], quant[0])
+                krope = _dequant_rows(kq_ref[0], kd_ref[0], quant[1])
             else:
                 ckv_ref, kr_ref = kv_refs
                 ckv = ckv_ref[0].astype(jnp.float32)         # (P, R)
@@ -579,10 +618,8 @@ def _mla_core(q_eff, q_rope, kv, block_table, pos, lane_pages, *,
                          preferred_element_type=jnp.float32)
                  + jnp.dot(qr_ref[0].astype(jnp.float32), krope.T,
                            preferred_element_type=jnp.float32)) * scale
-            kidx = (pl.program_id(1) * tp
-                    + jax.lax.broadcasted_iota(jnp.int32, (tp, 1), 0)[:, 0])
-            valid = kidx <= pos_ref[pl.program_id(0)]
-            s = jnp.where(valid[None, :], s, NEG_INF)
+            valid = _key_index(tp) <= pos_ref[pl.program_id(0)]  # (1, P)
+            s = jnp.where(valid, s, NEG_INF)
             _online_update(s, valid, lambda p: jnp.dot(
                 p, ckv, preferred_element_type=jnp.float32),
                 m_ref, l_ref, acc_ref)
@@ -590,14 +627,16 @@ def _mla_core(q_eff, q_rope, kv, block_table, pos, lane_pages, *,
 
         pj = lambda i, j, bt, ps, lp: bt[i, jnp.minimum(j, lp[i] - 1)]  # noqa: E731,E501
         page3 = lambda i, j, bt, ps, lp: (pj(i, j, bt, ps, lp), 0, 0)  # noqa: E731,E501
-        page2 = lambda i, j, bt, ps, lp: (pj(i, j, bt, ps, lp), 0)     # noqa: E731,E501
+        if quant:
+            kv_ops = (kv_ops[0], _col_leaf(kv_ops[1]),
+                      kv_ops[2], _col_leaf(kv_ops[3]))
         if quant:
             # packed trailing axes for q4_0 leaves — unpack is in-kernel
             kv_specs = [
                 pl.BlockSpec((1, tp, kv_ops[0].shape[-1]), page3),
-                pl.BlockSpec((1, tp), page2),
+                pl.BlockSpec((1, tp, 1), page3),
                 pl.BlockSpec((1, tp, kv_ops[2].shape[-1]), page3),
-                pl.BlockSpec((1, tp), page2),
+                pl.BlockSpec((1, tp, 1), page3),
             ]
         else:
             kv_specs = [
@@ -642,8 +681,8 @@ def _mla_core(q_eff, q_rope, kv, block_table, pos, lane_pages, *,
     else:
         in_specs = tuple(PS() for _ in args)
         out_specs = PS()
-    return shard_map(shard_run, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)(*args)
+    return jax.shard_map(shard_run, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)(*args)
 
 
 # ---------------------------------------------------------------------------
@@ -674,32 +713,38 @@ def pack_q4_rows(qs: jax.Array) -> jax.Array:
     """Pack int4-valued int8 rows two-per-byte along the trailing axis.
 
     qs: (..., D) int8 with every value in [-8, 7] (the q4_0 quantizer
-    stays in [-7, 7]); D must be even.  Byte ``i`` holds element ``2i``
-    in its low nibble and element ``2i + 1`` in its high nibble — the
-    GGUF q4_0 convention (SNIPPETS.md Snippet 3), so
+    stays in [-7, 7]); D must be even.  Byte ``i`` holds element ``i``
+    in its low nibble and element ``i + D/2`` in its high nibble — ggml's
+    q4_0 layout with the whole row as the block — so
     :func:`unpack_q4_rows` restores the original element order with two
-    arithmetic shifts and an interleave.
+    arithmetic shifts and one concatenation, no interleave (a lane
+    interleave is a shape cast the TPU compiler refuses).
     """
     width = qs.shape[-1]
     if width % 2:
         raise ValueError(f"q4_0 packing needs an even trailing dim; "
                          f"got {width}")
-    lo = jnp.bitwise_and(qs[..., 0::2], 0x0F)
-    hi = jnp.left_shift(qs[..., 1::2], 4)
+    half = width // 2
+    lo = jnp.bitwise_and(qs[..., :half], 0x0F)
+    hi = jnp.left_shift(qs[..., half:], 4)
     return jnp.bitwise_or(lo, hi).astype(jnp.int8)
 
 
-def unpack_q4_rows(packed: jax.Array) -> jax.Array:
-    """Invert :func:`pack_q4_rows`: (..., D/2) int8 -> (..., D) int8.
+def _q4_values(packed: jax.Array) -> jax.Array:
+    """(..., D/2) packed bytes -> (..., D) int32 nibble values.
 
-    Pure int8 arithmetic (VPU-friendly, runs inside the kernel tile
-    loaders): ``(b << 4) >> 4`` sign-extends the low nibble, ``b >> 4``
-    the high one; a stack + reshape restores the even/odd interleave.
-    """
-    lo = jnp.right_shift(jnp.left_shift(packed, 4), 4)
-    hi = jnp.right_shift(packed, 4)
-    return jnp.stack([lo, hi], axis=-1).reshape(
-        *packed.shape[:-1], 2 * packed.shape[-1])
+    32-bit arithmetic (the kernel tile loaders run this on the VPU, which
+    has no 8-bit shifts): ``(b << 28) >> 28`` sign-extends the low
+    nibble, ``(b << 24) >> 28`` the high one."""
+    b = packed.astype(jnp.int32)
+    lo = jnp.right_shift(jnp.left_shift(b, 28), 28)
+    hi = jnp.right_shift(jnp.left_shift(b, 24), 28)
+    return jnp.concatenate([lo, hi], axis=-1)
+
+
+def unpack_q4_rows(packed: jax.Array) -> jax.Array:
+    """Invert :func:`pack_q4_rows`: (..., D/2) int8 -> (..., D) int8."""
+    return _q4_values(packed).astype(jnp.int8)
 
 
 def quantize_kv_page_pool_q4(pool: jax.Array
@@ -789,7 +834,8 @@ def paged_attn_prefill_quant(q: jax.Array, k_qs: jax.Array, k_d: jax.Array,
                              scale: float | None = None,
                              active_pages: int | None = None,
                              impl: str | None = None,
-                             interpret: bool | None = None) -> jax.Array:
+                             interpret: bool | None = None,
+                             mesh=None) -> jax.Array:
     """Fused chunked-prefill GQA over quantized page pools.
 
     The caller has already quantized this chunk's K/V rows **once** and
@@ -808,12 +854,16 @@ def paged_attn_prefill_quant(q: jax.Array, k_qs: jax.Array, k_d: jax.Array,
     a previous page occupant (the paged analogue of the gather path's
     ``pos < start`` frontier check).  Because the page enumeration is
     fixed by the block table — independent of how the prompt was split
-    into chunks — outputs are bitwise chunk-size invariant: pages past a
-    query's horizon are fully masked, and fully-masked tiles are exact
-    no-ops in the online softmax.
+    into chunks — the Pallas kernel's outputs are bitwise chunk-size
+    invariant: pages past a query's horizon are fully masked, and
+    fully-masked tiles are exact no-ops in the online softmax.  The XLA
+    twin matches to float reassociation only (XLA blocks its einsums
+    and softmax sums by the chunk shape).
 
     Returns (B, C, H, Dv) f32.  Ring (windowed-local) tables must keep
     the gather path: their stored positions are not logical indices.
+    ``mesh``: as in :func:`paged_attn_decode_quant` (head-split
+    ``shard_map`` when the kv heads divide the ``model`` axis).
     """
     nj = _n_active(block_table, active_pages)
     return _attn_prefill_core(
@@ -823,22 +873,24 @@ def paged_attn_prefill_quant(q: jax.Array, k_qs: jax.Array, k_d: jax.Array,
         scale=(q.shape[-1] ** -0.5 if scale is None else scale),
         nj=nj, impl=_resolve_impl(impl),
         interpret=(_interpret_default() if interpret is None else interpret),
-        quant=_check_mode(mode))
+        quant=_check_mode(mode), mesh=mesh)
 
 
 @partial(jax.jit, static_argnames=("window", "softcap", "scale", "nj",
-                                   "impl", "interpret", "quant"))
+                                   "impl", "interpret", "quant", "mesh"))
 def _attn_prefill_core(q, kv, pos_pool, block_table, qpos, *,
                        window: int, softcap: float, scale: float, nj: int,
                        impl: str, interpret: bool,
-                       quant: str) -> jax.Array:
+                       quant: str, mesh=None) -> jax.Array:
     """Multi-query variant of :func:`_attn_core` for chunked prefill.
 
     Grid is the same ``(slot, logical_page)``; each step scores all C
     chunk queries against one page tile with a per-row (C, P) validity
     mask.  Rows are laid out ``(hkv, C, rep)`` so the score/probability
-    contractions stay grouped by kv head; the finish step transposes the
-    accumulator back to (C, H, Dv).  No lane clamp: every logical page in
+    contractions stay grouped by kv head: the wrapper arranges the
+    queries, their per-row positions (a (rows, 1) column) and the output
+    in that order outside the kernel, which then needs no shape cast
+    beyond merging leading axes.  No lane clamp: every logical page in
     ``[0, nj)`` is either allocated to the lane or the NULL page (whose
     rows are unwritten, ``pos = -1``), and revisit-dedup does not apply
     because prefill reads each page exactly once.
@@ -875,76 +927,100 @@ def _attn_prefill_core(q, kv, pos_pool, block_table, qpos, *,
                        preferred_element_type=jnp.float32)
         return o.reshape(b, c, h, dv)
 
-    rows = hkv * c * rep
+    def shard_run(block_table, qpos, q, *rest):
+        """Build + invoke the pallas_call from the (per-shard) operands."""
+        *kv_ops, pos_pool = rest
+        b, c, h, d = q.shape
+        hkv = kv_ops[0].shape[2]
+        rep = h // hkv
+        # each grid step takes a block of rb query rows per kv head (VMEM
+        # stays bounded for whole-prompt chunks); axis 0 runs over (slot,
+        # row block) as in _mla_prefill_core
+        rb = _row_block(c * rep, _MAX_ROWS // hkv)
+        nr = c * rep // rb
+        rows = hkv * rb
 
-    def kernel(bt_ref, qp_ref, q_ref, kq_ref, kd_ref, vq_ref, vd_ref,
-               pp_ref, o_ref, m_ref, l_ref, acc_ref):
-        del bt_ref
-        _init_accumulators(m_ref, l_ref, acc_ref)
-        kt = _dequant(kq_ref[0], kd_ref[0], quant)           # (P, Hkv, D)
-        qv = q_ref[0].astype(jnp.float32) * scale            # (C, H, D)
-        q2 = qv.reshape(c, hkv, rep, d).transpose(1, 0, 2, 3)
-        s = jax.lax.dot_general(                             # (Hkv, C*rep, P)
-            q2.reshape(hkv, c * rep, d), kt,
-            (((2,), (2,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32).reshape(rows, tp)
-        if softcap:
-            s = softcap * jnp.tanh(s / softcap)
-        pt = pp_ref[0]                                       # (P,)
-        qp = qp_ref[pl.program_id(0)]                        # (C,)
-        kidx = (pl.program_id(1) * tp
-                + jax.lax.broadcasted_iota(jnp.int32, (tp, 1), 0)[:, 0])
-        v2 = ((pt[None, :] >= 0) & (pt[None, :] <= qp[:, None])
-              & (kidx[None, :] <= qp[:, None]))              # (C, P)
-        if window:
-            v2 &= pt[None, :] > qp[:, None] - window
-        vr = jnp.broadcast_to(v2[None, :, None, :],
-                              (hkv, c, rep, tp)).reshape(rows, tp)
-        s = jnp.where(vr, s, NEG_INF)
+        def kernel(bt_ref, q_ref, qp_ref, kq_ref, kd_ref, vq_ref, vd_ref,
+                   pp_ref, o_ref, m_ref, l_ref, acc_ref):
+            del bt_ref
+            _init_accumulators(m_ref, l_ref, acc_ref)
+            kt = _dequant(kq_ref[0], kd_ref[0], quant)       # (P, Hkv, D)
+            qv = q_ref[0].astype(jnp.float32) * scale        # (Hkv, rb, D)
+            s = jax.lax.dot_general(                         # (Hkv, rb, P)
+                qv, kt, (((2,), (2,)), ((0,), (1,))),
+                preferred_element_type=jnp.float32).reshape(rows, tp)
+            if softcap:
+                s = softcap * jnp.tanh(s / softcap)
+            pt = pp_ref[0]                                   # (1, P)
+            qp = qp_ref[0].reshape(rows, 1)
+            vr = (pt >= 0) & (pt <= qp) & (_key_index(tp) <= qp)  # (rows, P)
+            if window:
+                vr &= pt > qp - window
+            s = jnp.where(vr, s, NEG_INF)
 
-        def v_tile(p):
-            o = jax.lax.dot_general(                         # (Hkv, C*rep, Dv)
-                p.reshape(hkv, c * rep, tp),
-                _dequant(vq_ref[0], vd_ref[0], quant),
-                (((2,), (0,)), ((0,), (1,))),
-                preferred_element_type=jnp.float32)
-            return o.reshape(rows, dv)
+            def v_tile(p):
+                o = jax.lax.dot_general(                     # (Hkv, rb, Dv)
+                    p.reshape(hkv, rb, tp),
+                    _dequant(vq_ref[0], vd_ref[0], quant),
+                    (((2,), (0,)), ((0,), (1,))),
+                    preferred_element_type=jnp.float32)
+                return o.reshape(rows, dv)
 
-        _online_update(s, vr, v_tile, m_ref, l_ref, acc_ref)
+            _online_update(s, vr, v_tile, m_ref, l_ref, acc_ref)
+            _finish(o_ref, acc_ref, l_ref, nj)
 
-        @pl.when(pl.program_id(1) == nj - 1)
-        def _():
-            l = jnp.maximum(l_ref[:, 0:1], 1e-30)
-            out = (acc_ref[...] / l).reshape(hkv, c, rep, dv)
-            o_ref[0] = out.transpose(1, 0, 2, 3).reshape(c, h, dv)
+        page4 = lambda i, j, bt: (bt[i // nr, j], 0, 0, 0)  # noqa: E731
+        page3 = lambda i, j, bt: (bt[i // nr, j], 0, 0)     # noqa: E731
+        lane4 = lambda i, j, bt: (i // nr, 0, i % nr, 0)    # noqa: E731
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b * nr, nj),
+            in_specs=[
+                pl.BlockSpec((1, hkv, rb, d), lane4),
+                pl.BlockSpec((1, hkv, rb, 1), lane4),
+                pl.BlockSpec((1, tp, hkv, kv_ops[0].shape[-1]), page4),
+                pl.BlockSpec((1, tp, hkv), page3),
+                pl.BlockSpec((1, tp, hkv, kv_ops[2].shape[-1]), page4),
+                pl.BlockSpec((1, tp, hkv), page3),
+                pl.BlockSpec((1, 1, tp), page3),
+            ],
+            out_specs=pl.BlockSpec((1, hkv, rb, dv), lane4),
+            scratch_shapes=[
+                pltpu.VMEM((rows, _LANES), jnp.float32),
+                pltpu.VMEM((rows, _LANES), jnp.float32),
+                pltpu.VMEM((rows, dv), jnp.float32),
+            ],
+        )
+        # rows in (hkv, C, rep) order: queries, their positions, and back
+        qg = q.reshape(b, c, hkv, rep, d).transpose(0, 2, 1, 3, 4)
+        qrow = jnp.broadcast_to(qpos[:, None, :, None], (b, hkv, c, rep))
+        o = pl.pallas_call(
+            kernel,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((b, hkv, c * rep, dv),
+                                           jnp.float32),
+            interpret=interpret,
+        )(block_table, qg.reshape(b, hkv, c * rep, d),
+          qrow.reshape(b, hkv, c * rep, 1), *kv_ops, _row_leaf(pos_pool))
+        return o.reshape(b, hkv, c, rep, dv).transpose(0, 2, 1, 3, 4
+                                                       ).reshape(b, c, h, dv)
 
-    page4 = lambda i, j, bt, qp: (bt[i, j], 0, 0, 0)  # noqa: E731
-    page3 = lambda i, j, bt, qp: (bt[i, j], 0, 0)     # noqa: E731
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, nj),
-        in_specs=[
-            pl.BlockSpec((1, c, h, d), lambda i, j, bt, qp: (i, 0, 0, 0)),
-            pl.BlockSpec((1, tp, hkv, kv[0].shape[-1]), page4),
-            pl.BlockSpec((1, tp, hkv), page3),
-            pl.BlockSpec((1, tp, hkv, kv[2].shape[-1]), page4),
-            pl.BlockSpec((1, tp, hkv), page3),
-            pl.BlockSpec((1, tp), lambda i, j, bt, qp: (bt[i, j], 0)),
-        ],
-        out_specs=pl.BlockSpec((1, c, h, dv),
-                               lambda i, j, bt, qp: (i, 0, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((rows, _LANES), jnp.float32),
-            pltpu.VMEM((rows, _LANES), jnp.float32),
-            pltpu.VMEM((rows, dv), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, c, h, dv), jnp.float32),
-        interpret=interpret,
-    )(block_table, qpos, q, *kv, pos_pool)
+    args = (block_table, qpos, q, *kv, pos_pool)
+    if mesh is None:
+        return shard_run(*args)
+    PS = jax.sharding.PartitionSpec
+    msize = mesh.shape.get("model", 1)
+    if msize > 1 and hkv % msize == 0 and h % msize == 0:
+        # head groups are independent, as in _attn_core
+        head4 = PS(None, None, "model", None)
+        head3 = PS(None, None, "model")
+        in_specs = (PS(), PS(), head4, head4, head3, head4, head3, PS())
+        out_specs = head4
+    else:
+        in_specs = tuple(PS() for _ in args)
+        out_specs = PS()
+    return jax.shard_map(shard_run, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)(*args)
 
 
 def paged_mla_prefill_quant(q_eff: jax.Array, q_rope: jax.Array,
@@ -956,7 +1032,8 @@ def paged_mla_prefill_quant(q_eff: jax.Array, q_rope: jax.Array,
                             rope_mode: str = "q8_0",
                             active_pages: int | None = None,
                             impl: str | None = None,
-                            interpret: bool | None = None) -> jax.Array:
+                            interpret: bool | None = None,
+                            mesh=None) -> jax.Array:
     """Fused chunked-prefill absorbed MLA over quantized latent pools.
 
     Write-then-attend like :func:`paged_attn_prefill_quant`, in absorbed
@@ -966,7 +1043,8 @@ def paged_mla_prefill_quant(q_eff: jax.Array, q_rope: jax.Array,
     per-head K/V is materialised, matching the decode path's math rather
     than the naive gather prefill's.  Latent pools store no positions:
     validity is purely ``logical_idx <= qpos[b, c]`` (padded rows carry
-    ``qpos = -1`` and come back zero).
+    ``qpos = -1`` and come back zero).  ``mesh``: as in
+    :func:`paged_mla_decode_quant` (query heads split on ``model``).
     """
     nj = _n_active(block_table, active_pages)
     return _mla_prefill_core(
@@ -974,14 +1052,14 @@ def paged_mla_prefill_quant(q_eff: jax.Array, q_rope: jax.Array,
         qpos.astype(jnp.int32),
         scale=scale, nj=nj, impl=_resolve_impl(impl),
         interpret=(_interpret_default() if interpret is None else interpret),
-        quant=(_check_mode(latent_mode), _check_mode(rope_mode)))
+        quant=(_check_mode(latent_mode), _check_mode(rope_mode)), mesh=mesh)
 
 
 @partial(jax.jit, static_argnames=("scale", "nj", "impl", "interpret",
-                                   "quant"))
+                                   "quant", "mesh"))
 def _mla_prefill_core(q_eff, q_rope, kv, block_table, qpos, *,
                       scale: float, nj: int, impl: str, interpret: bool,
-                      quant: tuple) -> jax.Array:
+                      quant: tuple, mesh=None) -> jax.Array:
     """Multi-query variant of :func:`_mla_core` for chunked prefill;
     rows are ``(C, h)``-ordered, validity is the per-row positional mask
     ``logical_idx <= qpos``."""
@@ -1006,59 +1084,79 @@ def _mla_prefill_core(q_eff, q_rope, kv, block_table, qpos, *,
         return jnp.einsum("bchl,blr->bchr", w, cs,
                           preferred_element_type=jnp.float32)
 
-    rows = c * h
+    def shard_run(block_table, qpos, q_eff, q_rope, *kv_ops):
+        """Build + invoke the pallas_call from the (per-shard) operands."""
+        b, c, h, r = q_eff.shape
+        rows = c * h
+        rb = _row_block(rows, _MAX_ROWS)
+        nr = rows // rb
 
-    def kernel(bt_ref, qp_ref, qe_ref, qr_ref, cq_ref, cd_ref, kq_ref,
-               kd_ref, o_ref, m_ref, l_ref, acc_ref):
-        del bt_ref
-        _init_accumulators(m_ref, l_ref, acc_ref)
-        ckv = _dequant(cq_ref[0], cd_ref[0], quant[0])       # (P, R)
-        krope = _dequant(kq_ref[0], kd_ref[0], quant[1])     # (P, Dr)
-        qe = qe_ref[0].astype(jnp.float32).reshape(rows, r)
-        qr = qr_ref[0].astype(jnp.float32).reshape(rows, dr)
-        s = (jnp.dot(qe, ckv.T, preferred_element_type=jnp.float32)
-             + jnp.dot(qr, krope.T,
-                       preferred_element_type=jnp.float32)) * scale
-        kidx = (pl.program_id(1) * tp
-                + jax.lax.broadcasted_iota(jnp.int32, (tp, 1), 0)[:, 0])
-        qp = qp_ref[pl.program_id(0)]                        # (C,)
-        v2 = kidx[None, :] <= qp[:, None]                    # (C, P)
-        vr = jnp.broadcast_to(v2[:, None, :],
-                              (c, h, tp)).reshape(rows, tp)
-        s = jnp.where(vr, s, NEG_INF)
-        _online_update(s, vr, lambda p: jnp.dot(
-            p, ckv, preferred_element_type=jnp.float32),
-            m_ref, l_ref, acc_ref)
+        def kernel(bt_ref, qe_ref, qr_ref, qp_ref, cq_ref, cd_ref, kq_ref,
+                   kd_ref, o_ref, m_ref, l_ref, acc_ref):
+            del bt_ref
+            _init_accumulators(m_ref, l_ref, acc_ref)
+            ckv = _dequant_rows(cq_ref[0], cd_ref[0], quant[0])    # (P, R)
+            krope = _dequant_rows(kq_ref[0], kd_ref[0], quant[1])  # (P, Dr)
+            qe = qe_ref[0].astype(jnp.float32)               # (rb, R)
+            qr = qr_ref[0].astype(jnp.float32)               # (rb, Dr)
+            s = (jnp.dot(qe, ckv.T, preferred_element_type=jnp.float32)
+                 + jnp.dot(qr, krope.T,
+                           preferred_element_type=jnp.float32)) * scale
+            vr = _key_index(tp) <= qp_ref[0]                 # (rb, P)
+            s = jnp.where(vr, s, NEG_INF)
+            _online_update(s, vr, lambda p: jnp.dot(
+                p, ckv, preferred_element_type=jnp.float32),
+                m_ref, l_ref, acc_ref)
+            _finish(o_ref, acc_ref, l_ref, nj)
 
-        @pl.when(pl.program_id(1) == nj - 1)
-        def _():
-            l = jnp.maximum(l_ref[:, 0:1], 1e-30)
-            o_ref[0] = (acc_ref[...] / l).reshape(c, h, r)
+        # grid axis 0 runs over (slot, row block): each step holds rb
+        # query rows, so VMEM stays bounded at wide head counts (128 heads
+        # x C=32 rows would not fit whole)
+        page3 = lambda i, j, bt: (bt[i // nr, j], 0, 0)  # noqa: E731
+        lane3 = lambda i, j, bt: (i // nr, i % nr, 0)    # noqa: E731
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b * nr, nj),
+            in_specs=[
+                pl.BlockSpec((1, rb, r), lane3),
+                pl.BlockSpec((1, rb, dr), lane3),
+                pl.BlockSpec((1, rb, 1), lane3),
+                pl.BlockSpec((1, tp, kv_ops[0].shape[-1]), page3),
+                pl.BlockSpec((1, tp, 1), page3),
+                pl.BlockSpec((1, tp, kv_ops[2].shape[-1]), page3),
+                pl.BlockSpec((1, tp, 1), page3),
+            ],
+            out_specs=pl.BlockSpec((1, rb, r), lane3),
+            scratch_shapes=[
+                pltpu.VMEM((rb, _LANES), jnp.float32),
+                pltpu.VMEM((rb, _LANES), jnp.float32),
+                pltpu.VMEM((rb, r), jnp.float32),
+            ],
+        )
+        # rows in (C, H) order; each row carries its query's position
+        qrow = jnp.broadcast_to(qpos[:, :, None], (b, c, h))
+        o = pl.pallas_call(
+            kernel,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((b, rows, r), jnp.float32),
+            interpret=interpret,
+        )(block_table, q_eff.reshape(b, rows, r), q_rope.reshape(b, rows, dr),
+          qrow.reshape(b, rows, 1), kv_ops[0], _col_leaf(kv_ops[1]),
+          kv_ops[2], _col_leaf(kv_ops[3]))
+        return o.reshape(b, c, h, r)
 
-    page3 = lambda i, j, bt, qp: (bt[i, j], 0, 0)  # noqa: E731
-    page2 = lambda i, j, bt, qp: (bt[i, j], 0)     # noqa: E731
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, nj),
-        in_specs=[
-            pl.BlockSpec((1, c, h, r), lambda i, j, bt, qp: (i, 0, 0, 0)),
-            pl.BlockSpec((1, c, h, dr), lambda i, j, bt, qp: (i, 0, 0, 0)),
-            pl.BlockSpec((1, tp, kv[0].shape[-1]), page3),
-            pl.BlockSpec((1, tp), page2),
-            pl.BlockSpec((1, tp, kv[2].shape[-1]), page3),
-            pl.BlockSpec((1, tp), page2),
-        ],
-        out_specs=pl.BlockSpec((1, c, h, r),
-                               lambda i, j, bt, qp: (i, 0, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((rows, _LANES), jnp.float32),
-            pltpu.VMEM((rows, _LANES), jnp.float32),
-            pltpu.VMEM((rows, r), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, c, h, r), jnp.float32),
-        interpret=interpret,
-    )(block_table, qpos, q_eff, q_rope, *kv)
+    args = (block_table, qpos, q_eff, q_rope, *kv)
+    if mesh is None:
+        return shard_run(*args)
+    PS = jax.sharding.PartitionSpec
+    msize = mesh.shape.get("model", 1)
+    if msize > 1 and h % msize == 0:
+        # query heads split; the per-token latent pools are read whole
+        headq = PS(None, None, "model", None)
+        in_specs = (PS(), PS(), headq, headq, *(PS() for _ in kv))
+        out_specs = headq
+    else:
+        in_specs = tuple(PS() for _ in args)
+        out_specs = PS()
+    return jax.shard_map(shard_run, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)(*args)

@@ -9,16 +9,10 @@ import to build these meshes on CPU.
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5; older versions have no explicit axis types
-    from jax.sharding import AxisType
-except ImportError:
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def _make_mesh(shape, axes):
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes,
                          axis_types=(AxisType.Auto,) * len(axes))
 

@@ -6,12 +6,17 @@ serve batched generation requests.
 
   PYTHONPATH=src python -m repro.launch.serve --arch qwen2-1.5b --reduced \
       --policy DQ3_K_M --requests 4 --max-new 16
+
+:func:`main` is :func:`parse_args` -> :func:`load_quantized` ->
+:func:`serve_requests`; ``chip_smoke.py`` drives the same three.  The
+persistent compilation cache goes where ``launch/compile_cache.py`` says.
 """
 
 from __future__ import annotations
 
 import argparse
 
+import jax.numpy as jnp
 import numpy as np
 
 from ..checkpoint import checkpoint as ckpt
@@ -21,10 +26,11 @@ from ..models import spec as mspec
 from ..models.model import Model
 from ..serving.engine import Engine, Request
 from ..serving.sampler import SamplerConfig
+from .compile_cache import enable_compile_cache
 from .mesh import describe_mesh, mesh_from_spec
 
 
-def main(argv=None):
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")
@@ -116,13 +122,20 @@ def main(argv=None):
     ap.add_argument("--temperature", type=float, default=0.6)
     ap.add_argument("--top-p", type=float, default=0.95)
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args(argv)
+    ap.add_argument("--dtype", default="bfloat16",
+                    choices=("bfloat16", "float32"),
+                    help="activation dtype; unquantized KV pools are "
+                         "stored in it too")
+    return ap.parse_args(argv)
 
+
+def load_quantized(args):
+    """``(cfg, quantized params)``: the checkpoint in ``--ckpt-dir`` (else
+    weights initialised from ``--seed``) quantized with ``--policy``."""
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
     policy = get_policy(args.policy)
-    mesh = mesh_from_spec(args.mesh)
 
     if args.ckpt_dir and ckpt.latest_step(args.ckpt_dir) is not None:
         tree, _ = ckpt.restore(args.ckpt_dir)
@@ -136,12 +149,19 @@ def main(argv=None):
     print(f"quantizing {cfg.name} with {policy.name}: "
           f"{rep.gib:.2f} GiB @ {rep.avg_bits:.2f} bits/weight "
           f"(bf16 would be {rep.total_params * 2 / 1024**3:.2f} GiB)")
-    qparams = quantize_params(cfg, params, policy)
+    return cfg, quantize_params(cfg, params, policy)
+
+
+def serve_requests(args, cfg, qparams):
+    """Build the engine ``args`` describe, serve ``--requests`` seeded
+    random prompts, print each request and the stats report, and return
+    ``(engine, finished requests)``."""
+    mesh = mesh_from_spec(args.mesh)
     # no weight-sharding step here: the Engine lays the weights out on the
     # mesh it serves on (Engine(mesh=...) shards, Engine(mesh=None)
     # rejects pre-sharded params), so the "weights sharded on one mesh,
     # engine serving unsharded" split is structurally impossible
-    model = Model(cfg)
+    model = Model(cfg, dtype=jnp.dtype(args.dtype))
     plan = None
     if args.chaos is not None:
         from ..serving.faults import FaultPlan
@@ -197,6 +217,14 @@ def main(argv=None):
         hits = ", ".join(f"{f['kind']}@{f['step']}" for f in stats.fault_log)
         print(f"chaos: {stats.faults_injected} faults landed"
               + (f" ({hits})" if hits else ""))
+    return engine, done
+
+
+def main(argv=None):
+    enable_compile_cache()
+    args = parse_args(argv)
+    cfg, qparams = load_quantized(args)
+    _, done = serve_requests(args, cfg, qparams)
     return done
 
 

@@ -21,7 +21,7 @@ import jax.numpy as jnp
 from ..configs.base import ModelConfig
 from ..kernels import paged_attn
 from . import paged
-from .common import apply_rope, linear, rms_norm, softcap
+from .common import apply_rope, gather_heads, linear, rms_norm, softcap
 
 NEG_INF = -2.0e38
 
@@ -319,7 +319,8 @@ def attn_decode_paged(p: dict, cfg: ModelConfig, x: jax.Array, cache: dict,
     length = cache_len(cfg, max_len, local)
     b = x.shape[0]
     if kernel == "gather" and not kv_quant:
-        dense = {k: paged.gather_pages(cache[k], block_table, length)
+        dense = {k: gather_heads(
+                     paged.gather_pages(cache[k], block_table, length), mesh)
                  for k in ("k", "v", "pos")}
         delta, dnew = attn_decode(p, cfg, x, dense, pos, local=local,
                                   live=live)
@@ -355,7 +356,7 @@ def attn_decode_paged(p: dict, cfg: ModelConfig, x: jax.Array, cache: dict,
             cpos = paged.gather_pages(new["pos"], block_table, length)
             o = _attend_cache(cfg, q, ck, cv, cpos, pos,
                               local=local).astype(x.dtype)
-            return linear(p["o_proj"], o), new
+            return linear(p["o_proj"], gather_heads(o, mesh)), new
         o = paged_attn.paged_attn_decode_quant(
             q[:, 0], kq, kd, vq, vd, new["pos"], block_table, pos,
             mode=kv_quant,
@@ -363,7 +364,7 @@ def attn_decode_paged(p: dict, cfg: ModelConfig, x: jax.Array, cache: dict,
             scale=cfg.head_dim ** -0.5, active_pages=active_pages,
             lane_pages=lane_pages, mesh=mesh)
         o = o.reshape(b, 1, cfg.n_heads * cfg.head_dim).astype(x.dtype)
-        return linear(p["o_proj"], o), new
+        return linear(p["o_proj"], gather_heads(o, mesh)), new
 
     new = {
         "k": paged.scatter_token(cache["k"], block_table, slot, k[:, 0],
@@ -379,7 +380,7 @@ def attn_decode_paged(p: dict, cfg: ModelConfig, x: jax.Array, cache: dict,
         scale=cfg.head_dim ** -0.5, active_pages=active_pages,
         lane_pages=lane_pages, mesh=mesh)
     o = o.reshape(b, 1, cfg.n_heads * cfg.head_dim).astype(x.dtype)
-    return linear(p["o_proj"], o), new
+    return linear(p["o_proj"], gather_heads(o, mesh)), new
 
 
 def chunk_key_positions(old_pos: jax.Array, positions: jax.Array,
@@ -424,7 +425,7 @@ def attn_prefill_chunk(p: dict, cfg: ModelConfig, x: jax.Array, cache: dict,
                        chunk_len: jax.Array, *, local: bool, max_len: int,
                        block_table: jax.Array | None = None,
                        kv_quant=None, kernel: str | None = None,
-                       active_pages: int | None = None,
+                       active_pages: int | None = None, mesh=None,
                        ) -> tuple[jax.Array, dict]:
     """One prefill chunk against an existing (pooled) cache.
 
@@ -438,17 +439,17 @@ def attn_prefill_chunk(p: dict, cfg: ModelConfig, x: jax.Array, cache: dict,
     ``block_table`` is given; with ``kv_quant`` the paged pools are
     quantized and this chunk's K/V are quantized once up front, so the
     chunk's own keys are attended through the same round-tripped values
-    every later read sees and outputs are bitwise independent of the
-    chunk size.
+    every later read sees, whatever the chunk size.
 
     ``kernel="fused"`` on a quantized full-horizon (non-ring) layer runs
     the *write-then-attend* path: the quantized rows are scattered into
     their pages first, then every chunk query attends the pools in place
     (:func:`repro.kernels.paged_attn.paged_attn_prefill_quant`) — packed
     pages stay packed, no dense dequantised view is ever materialised,
-    and the output is bitwise chunk-size invariant because the page
-    enumeration order does not depend on the chunk split.  Ring layers
-    and ``kernel="gather"`` keep the dequantizing-gather reference path.
+    and the page enumeration order does not depend on the chunk split.
+    Ring layers and ``kernel="gather"`` keep the dequantizing-gather
+    reference path.  ``mesh``: the serving mesh, forwarded to the fused
+    kernel (runs it under ``shard_map``).
     """
     kv_quant = _kv_mode(kv_quant)
     kernel = kernel or default_paged_kernel()
@@ -483,9 +484,9 @@ def attn_prefill_chunk(p: dict, cfg: ModelConfig, x: jax.Array, cache: dict,
             q, new["k_qs"], new["k_d"], new["v_qs"], new["v_d"],
             new["pos"], block_table, qpos, mode=kv_quant, window=0,
             softcap=cfg.attn_softcap, scale=cfg.head_dim ** -0.5,
-            active_pages=active_pages)
+            active_pages=active_pages, mesh=mesh)
         o = o.reshape(b, c, cfg.n_heads * cfg.head_dim).astype(x.dtype)
-        return linear(p["o_proj"], o), new
+        return linear(p["o_proj"], gather_heads(o, mesh)), new
 
     k_qs = k_d = v_qs = v_d = None
     if kv_quant:
@@ -520,7 +521,7 @@ def attn_prefill_chunk(p: dict, cfg: ModelConfig, x: jax.Array, cache: dict,
 
     o = _chunk_attn(q.astype(ck.dtype), kk, vv, mask_fn, cfg.attn_softcap)
     o = o.reshape(b, c, cfg.n_heads * cfg.head_dim).astype(x.dtype)
-    out = linear(p["o_proj"], o)
+    out = linear(p["o_proj"], gather_heads(o, mesh))
 
     # write the chunk into the cache (last writer wins on ring collisions)
     idx = (positions % length).astype(jnp.int32)

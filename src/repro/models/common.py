@@ -40,6 +40,24 @@ def linear(w, x: jax.Array, bias=None, *, precision=None) -> jax.Array:
     return y
 
 
+def gather_heads(x: jax.Array, mesh) -> jax.Array:
+    """``x`` replicated on ``mesh`` (returned as is without one).
+
+    Attention leaves its output split over heads on the ``model`` axis
+    (the fused kernels run head-split under ``shard_map``, and GSPMD
+    splits the gather paths the same way after the head-sharded pools).
+    Fed so to ``o_proj``, whose contraction runs over the heads, the
+    partitioner computes per-device partial sums and all-reduces them:
+    the sum is taken in another order than on one device, and the
+    logits drift.  Gathering the heads first keeps every device's
+    contraction whole, as ``Engine(mesh=...)`` promises.
+    """
+    if mesh is None:
+        return x
+    return jax.lax.with_sharding_constraint(
+        x, jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec()))
+
+
 def embed_lookup(w, tokens: jax.Array, dtype=jnp.bfloat16) -> jax.Array:
     """Embedding lookup; ``w`` is (d_model, vocab) (blocks along d_model)."""
     if isinstance(w, QTensor):

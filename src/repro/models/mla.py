@@ -19,7 +19,7 @@ from ..kernels import paged_attn
 from . import paged
 from .attention import (_chunk_attn, causal_mask_fn, chunk_key_positions,
                         chunk_mask_fn, default_paged_kernel, NEG_INF)
-from .common import apply_rope, linear, rms_norm
+from .common import apply_rope, gather_heads, linear, rms_norm
 
 from ..core.qtensor import QTensor
 
@@ -197,7 +197,8 @@ def mla_decode_paged(p: dict, cfg: ModelConfig, x: jax.Array, cache: dict,
         raise ValueError(f"unknown paged decode kernel {kernel!r}")
     lq = paged.as_layer_quant(kv_quant) if kv_quant else None
     if kernel == "gather" and not kv_quant:
-        dense = {k: paged.gather_pages(cache[k], block_table, max_len)
+        dense = {k: gather_heads(
+                     paged.gather_pages(cache[k], block_table, max_len), mesh)
                  for k in ("c_kv", "k_rope")}
         delta, dnew = mla_decode(p, cfg, x, dense, pos, live=live)
         bidx = jnp.arange(x.shape[0])
@@ -232,7 +233,8 @@ def mla_decode_paged(p: dict, cfg: ModelConfig, x: jax.Array, cache: dict,
             krope = paged.gather_pages_quant(kq, kd, block_table, max_len,
                                              lq.kv)
             return _absorbed_attend(p, cfg, x.dtype, q_nope, q_rope,
-                                    ckv, krope, pos), new
+                                    gather_heads(ckv, mesh),
+                                    gather_heads(krope, mesh), pos), new
     else:
         new = {
             "c_kv": paged.scatter_token(cache["c_kv"], block_table, idx,
@@ -259,7 +261,7 @@ def mla_decode_paged(p: dict, cfg: ModelConfig, x: jax.Array, cache: dict,
     o = jnp.einsum("bhr,rhd->bhd", lat.astype(dt), w_vb,
                    preferred_element_type=jnp.float32)        # (B,H,dv)
     o = o.reshape(b, 1, nh * dv).astype(x.dtype)
-    return linear(p["o_proj"], o), new
+    return linear(p["o_proj"], gather_heads(o, mesh)), new
 
 
 def mla_prefill_chunk(p: dict, cfg: ModelConfig, x: jax.Array, cache: dict,
@@ -267,7 +269,7 @@ def mla_prefill_chunk(p: dict, cfg: ModelConfig, x: jax.Array, cache: dict,
                       chunk_len: jax.Array, *, max_len: int,
                       block_table: jax.Array | None = None,
                       kv_quant=None, kernel: str | None = None,
-                      active_pages: int | None = None,
+                      active_pages: int | None = None, mesh=None,
                       ) -> tuple[jax.Array, dict]:
     """One prefill chunk against the compressed-latent cache.
 
@@ -284,7 +286,9 @@ def mla_prefill_chunk(p: dict, cfg: ModelConfig, x: jax.Array, cache: dict,
     pages first, then every chunk query attends the packed pools in place
     (:func:`repro.kernels.paged_attn.paged_mla_prefill_quant`) — no dense
     dequantised latent view is ever materialised.  ``kernel="gather"``
-    keeps the naive-materialisation reference path.
+    keeps the naive-materialisation reference path.  ``mesh``: the
+    serving mesh, forwarded to the fused kernel (runs it under
+    ``shard_map``).
     """
     b, c, _ = x.shape
     nh = cfg.n_heads
@@ -324,11 +328,11 @@ def mla_prefill_chunk(p: dict, cfg: ModelConfig, x: jax.Array, cache: dict,
             q_eff.astype(dt), q_rope, new["c_kv_qs"], new["c_kv_d"],
             new["k_rope_qs"], new["k_rope_d"], block_table, qpos,
             scale=(dn + dr) ** -0.5, latent_mode=lq.latent,
-            rope_mode=lq.kv, active_pages=active_pages)
+            rope_mode=lq.kv, active_pages=active_pages, mesh=mesh)
         o = jnp.einsum("bchr,rhd->bchd", lat.astype(dt), w_vb,
                        preferred_element_type=jnp.float32)
         o = o.reshape(b, c, nh * dv).astype(x.dtype)
-        return linear(p["o_proj"], o), new
+        return linear(p["o_proj"], gather_heads(o, mesh)), new
 
     c_qs = c_d = kr_qs = kr_d = None
     if kv_quant:
@@ -340,8 +344,8 @@ def mla_prefill_chunk(p: dict, cfg: ModelConfig, x: jax.Array, cache: dict,
                                          max_len, lq.kv)
         # quantize the chunk's latents once, up front: in-chunk attention
         # uses the round-tripped view and the same qs/d are scattered
-        # below, so in-chunk and cross-chunk reads are identical and the
-        # output is bitwise independent of the chunk size
+        # below, so in-chunk and cross-chunk reads see identical values
+        # whatever the chunk size
         c_qs, c_d, c_att = paged.roundtrip_quant(c_new, lq.latent)
         kr_qs, kr_d, kr_att = paged.roundtrip_quant(kr_new, lq.kv)
     elif block_table is not None:
@@ -370,7 +374,7 @@ def mla_prefill_chunk(p: dict, cfg: ModelConfig, x: jax.Array, cache: dict,
 
     o = _chunk_attn(q, k, v, mask_fn, 0.0)
     o = o.reshape(b, c, nh * dv).astype(x.dtype)
-    out = linear(p["o_proj"], o)
+    out = linear(p["o_proj"], gather_heads(o, mesh))
 
     idx = positions.astype(jnp.int32)
     ok = valid_tok                          # full horizon: no ring collisions

@@ -370,7 +370,8 @@ class Model:
                       max_len: int, block_tables=None, page_size: int = 0,
                       kv_quant: str | None = None,
                       kernel: str | None = None,
-                      active_pages: tuple[int, int] | None = None):
+                      active_pages: tuple[int, int] | None = None,
+                      mesh=None):
         """One chunked-prefill step over the pooled decode cache.
 
         tokens: (B, C) int32, right-padded per row; start: (B,) absolute
@@ -387,8 +388,8 @@ class Model:
         the write-then-attend prefill kernels — packed pages stay packed;
         ``"gather"`` keeps the dequantizing-gather reference.
         ``active_pages``: optional static ``(n_full, n_ring)`` bound on
-        the fused prefill kernels' page loops, as in
-        :meth:`decode_step_paged`.
+        the fused prefill kernels' page loops, and ``mesh`` the serving
+        mesh the fused kernels run on, both as in :meth:`decode_step_paged`.
         """
         cfg = self.cfg
         if cfg.frontend == "vit" or cfg.is_encdec:
@@ -400,7 +401,7 @@ class Model:
         self._check_paged_quant(kv_quant)
         paged = (None if block_tables is None
                  else (block_tables, page_size, max_len, kv_quant, kernel,
-                       active_pages))
+                       active_pages, mesh))
         c = tokens.shape[1]
         x = self._embed_tokens(params, tokens)
         positions = start[:, None] + jnp.arange(c)[None, :]

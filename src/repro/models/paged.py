@@ -285,9 +285,8 @@ def roundtrip_quant(val: jnp.ndarray, mode: str = "q8_0"):
     quantized kernels compute the same product), so a prefill chunk that
     attends its *own* K/V through this view — and scatters the returned
     ``qs``/``d`` directly via :func:`scatter_chunk`, never quantizing
-    twice — produces outputs that are bitwise independent of the chunk
-    size: in-chunk and cross-chunk reads go through one identical round
-    trip.
+    twice — reads the same stored values whatever the chunk size:
+    in-chunk and cross-chunk reads go through one identical round trip.
     """
     qs, d = quantize_rows(val, mode)
     return qs, d, dequant_rows(qs, d, mode)

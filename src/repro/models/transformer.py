@@ -257,9 +257,9 @@ def prefill_chunk_layer(cfg: ModelConfig, p: dict, layer: int, x: jax.Array,
 
     if kind in ("attn", "local_attn"):
         local = kind == "local_attn"
-        bt, kv_quant, kernel, ap = None, None, None, None
+        bt, kv_quant, kernel, ap, mesh = None, None, None, None, None
         if paged is not None:
-            block_tables, _, _, kv_quant, kernel, active = paged
+            block_tables, _, _, kv_quant, kernel, active, mesh = paged
             kv_quant = resolve_layer_quant(kv_quant, cfg, layer)
             # MLA latents always span the full horizon (no ring bound)
             use_ring = local and not cfg.mla
@@ -271,12 +271,12 @@ def prefill_chunk_layer(cfg: ModelConfig, p: dict, layer: int, x: jax.Array,
             delta, cache_new = mla.mla_prefill_chunk(
                 p, cfg, x, cache, positions, start, chunk_len,
                 max_len=max_len, block_table=bt, kv_quant=kv_quant,
-                kernel=kernel, active_pages=ap)
+                kernel=kernel, active_pages=ap, mesh=mesh)
         else:
             delta, cache_new = attention.attn_prefill_chunk(
                 p, cfg, x, cache, positions, start, chunk_len, local=local,
                 max_len=max_len, block_table=bt, kv_quant=kv_quant,
-                kernel=kernel, active_pages=ap)
+                kernel=kernel, active_pages=ap, mesh=mesh)
         x = x + delta
     elif kind == "rglru":
         delta, cache_new = rglru.rglru_prefill_chunk(

@@ -700,7 +700,13 @@ class Engine:
             # mesh it serves on, so weight sharding and engine sharding
             # cannot disagree
             from ..parallel import sharding as _sh
-            self.params = jax.device_put(
+            # abstract (ShapeDtypeStruct) params just take the layout: such
+            # an engine can only compile, e.g. for a described topology
+            self.params = jax.tree_util.tree_map(
+                lambda x, s: (jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                                   sharding=s)
+                              if isinstance(x, jax.ShapeDtypeStruct)
+                              else jax.device_put(x, s)),
                 params,
                 _sh.tree_shardings(params, model.cfg, mesh,
                                    plan=getattr(model, "plan", None)))
@@ -768,7 +774,7 @@ class Engine:
                                kv_quant=self.kv_quant, mesh=self.mesh)
         chunk_fn = partial(model.prefill_chunk, max_len=max_len,
                            page_size=page_size, kv_quant=self.kv_quant,
-                           kernel=self.kernel)
+                           kernel=self.kernel, mesh=self.mesh)
         # serve() fills this in with the pool layout before the first
         # traced step; the wrappers read it at trace time (deterministic
         # per cache shape, so retraces agree)
@@ -815,10 +821,13 @@ class Engine:
           live sharded across the mesh (capacity) and stream in via
           all-gather, so every weight contraction is computed whole.
           Splitting the contraction instead (Megatron-style psum on
-          o_proj/down_proj) is faster per step but reassociates the f32
-          reduction (~1e-5 logit drift, enough to flip near-tied greedy
-          argmaxes); the engine picks bit-exactness — sharded serve
-          output is bitwise identical to the single-device engine.
+          o_proj/down_proj) is faster per step but reassociates the
+          reduction (enough to flip near-tied greedy argmaxes); the
+          engine picks bit-exactness — sharded serve output is bitwise
+          identical to the single-device engine.  The head-split
+          attention output is gathered before ``o_proj`` for the same
+          reason (``models.common.gather_heads``): left split, the
+          partitioner sums ``o_proj`` per device and all-reduces.
         * the **new cache** leaves carry explicit
           ``with_sharding_constraint``s from ``self._cache_shardings``,
           pinning the pool layout across steps instead of letting GSPMD
@@ -1005,12 +1014,7 @@ class Engine:
         n_ring = paged.pages_for(self._ring_len, P) if (use_paged
                                                         and self._has_ring) else 0
         if use_paged:
-            num_pages = self.num_pages or (
-                paged.RESERVED_PAGES + slots * (n_full + n_ring))
-            if self.mesh is not None:
-                # page-axis shardings need every mesh axis to divide the
-                # pool evenly; padding with never-allocated pages is free
-                num_pages += -num_pages % self.mesh.size
+            num_pages = self.pool_pages(slots)
             pool = PagePool(num_pages)
             cache = model.init_paged_cache(num_pages, P, slots, dtype=dtype,
                                            kv_quant=self.kv_quant)
@@ -1975,28 +1979,33 @@ class Engine:
         self.last_stats = agg
         return done
 
-    def compile_decode_step(self, slots: int, num_pages: int | None = None):
-        """AOT-compile one batched paged decode step — the steady-state
-        serving hot loop at its worst-case page horizon — and return the
-        ``jax.stages.Compiled``.  The bench layer reads its HLO and cost
-        analysis (``benchmarks/engine_bench.py --mesh`` gates the measured
-        step time against ``roofline.analysis`` on exactly this
-        executable).  Under ``Engine(mesh=...)`` the input avals carry the
-        same shardings ``serve`` lays the cache out with, so the compiled
-        module is the sharded one.  Requires ``jit=True`` and
-        ``page_size > 0``."""
-        if not self.page_size:
-            raise ValueError("compile_decode_step requires the paged cache "
-                             "(page_size > 0)")
-        if not hasattr(self._decode_paged, "lower"):
-            raise ValueError("compile_decode_step requires jit=True")
+    def _table_pages(self) -> tuple[int, int]:
+        """Logical pages of one lane's (full, ring) block tables."""
         P = self.page_size
-        n_full = paged.pages_for(self.max_len, P) if self._has_full else 0
-        n_ring = paged.pages_for(self._ring_len, P) if self._has_ring else 0
-        num_pages = num_pages or self.num_pages or (
-            paged.RESERVED_PAGES + slots * (n_full + n_ring))
+        return (paged.pages_for(self.max_len, P) if self._has_full else 0,
+                paged.pages_for(self._ring_len, P) if self._has_ring else 0)
+
+    def pool_pages(self, slots: int) -> int:
+        """Pages of the pool :meth:`serve` builds for ``slots`` lanes:
+        ``num_pages`` when given, else every lane's worst case.  Under a
+        mesh it is padded so every mesh axis divides the page axis; the
+        padding pages are never allocated."""
+        n = self.num_pages or (paged.RESERVED_PAGES
+                               + slots * sum(self._table_pages()))
         if self.mesh is not None:
-            num_pages += -num_pages % self.mesh.size
+            n += -n % self.mesh.size
+        return n
+
+    def _abstract_step_inputs(self, fn, slots: int):
+        """Cache avals and block tables for compiling ``fn`` over the
+        ``slots``-lane pool :meth:`serve` builds, with the shardings it
+        lays the cache out with under a mesh."""
+        if not self.page_size:
+            raise ValueError("compiling a step requires the paged cache "
+                             "(page_size > 0)")
+        if not hasattr(fn, "lower"):
+            raise ValueError("compiling a step requires jit=True")
+        P, num_pages = self.page_size, self.pool_pages(slots)
         specs = self.model.paged_cache_specs(num_pages, P, slots,
                                              dtype=self.model.dtype,
                                              kv_quant=self.kv_quant)
@@ -2018,20 +2027,47 @@ class Engine:
         cache = {k: jax.ShapeDtypeStruct(
                      s.shape, s.dtype, sharding=sh[k] if sh else None)
                  for k, s in specs.items()}
+        n_full, n_ring = self._table_pages()
+        tables = {"full": jax.ShapeDtypeStruct((slots, max(n_full, 1)),
+                                               jnp.int32),
+                  "ring": jax.ShapeDtypeStruct((slots, max(n_ring, 1)),
+                                               jnp.int32)}
+        return cache, tables
+
+    def compile_decode_step(self, slots: int):
+        """AOT-compile one batched paged decode step — the steady-state
+        serving hot loop at its worst-case page horizon — and return the
+        ``jax.stages.Compiled``.  The bench layer reads its HLO and cost
+        analysis (``benchmarks/engine_bench.py --mesh`` gates the measured
+        step time against ``roofline.analysis`` on exactly this
+        executable).  Under ``Engine(mesh=...)`` the input avals carry the
+        same shardings ``serve`` lays the cache out with, so the compiled
+        module is the sharded one.  Requires ``jit=True`` and
+        ``page_size > 0``."""
+        cache, tables = self._abstract_step_inputs(self._decode_paged, slots)
         i32 = partial(jax.ShapeDtypeStruct, dtype=jnp.int32)
         toks, pos = i32((slots,)), i32((slots,))
-        tables = {"full": i32((slots, max(n_full, 1))),
-                  "ring": i32((slots, max(n_ring, 1)))}
         live = jax.ShapeDtypeStruct((slots,), jnp.bool_)
         active = None
         lane_pages = None
         if self.kernel == "fused":
+            n_full, n_ring = self._table_pages()
             active = (_bucket_pages(n_full, n_full),
                       _bucket_pages(n_ring, n_ring))
             lane_pages = {"full": i32((slots,)), "ring": i32((slots,))}
         return self._decode_paged.lower(
             self.params, cache, toks, pos, tables, live=live,
             active_pages=active, lane_pages=lane_pages).compile()
+
+    def compile_prefill_step(self, slots: int):
+        """AOT-compile one batched chunked-prefill step (``prefill_chunk``
+        tokens per lane) as :meth:`serve` runs it, like
+        :meth:`compile_decode_step`."""
+        cache, tables = self._abstract_step_inputs(self._chunk, slots)
+        i32 = partial(jax.ShapeDtypeStruct, dtype=jnp.int32)
+        return self._chunk.lower(
+            self.params, cache, i32((slots, self.prefill_chunk)),
+            i32((slots,)), i32((slots,)), block_tables=tables).compile()
 
     # -- internals -----------------------------------------------------------
     def _kind_page_bytes(self) -> tuple[int, int]:

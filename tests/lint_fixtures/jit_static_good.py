@@ -28,6 +28,12 @@ def dynamic_data_ok(x, y):
     return x @ y + jnp.ones((8, 128))
 
 
+@jax.jit
+def broadcast_data_ok(x):
+    # broadcast_to's first argument is data; its second is the shape
+    return jnp.broadcast_to(x[:8, None], (8, 4))
+
+
 def not_jitted(x, n):
     # no jit decorator: Python bounds are concrete
     for _ in range(n):

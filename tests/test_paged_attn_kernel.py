@@ -1,7 +1,7 @@
 """Fused paged-attention decode kernels vs the gather reference.
 
-Two layers of parity (kernels/common.py semantics: on CPU the Pallas
-kernels run ``interpret=True``; ``REPRO_PALLAS_INTERPRET=1`` forces it):
+Two layers of parity (on CPU the Pallas kernels run ``interpret=True``,
+kernels/common.py):
 
   * kernel-level — :func:`repro.kernels.paged_attn.paged_attn_decode` /
     ``paged_mla_decode`` against a dense numpy oracle on hand-built page
@@ -19,6 +19,7 @@ kernels run ``interpret=True``; ``REPRO_PALLAS_INTERPRET=1`` forces it):
 
 import dataclasses
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -309,6 +310,93 @@ def test_mla_kernel_matches_dense_oracle(impl):
             w = np.exp(s - s.max())
             w /= w.sum()
             assert np.max(np.abs(got[i, hh] - w @ cs)) < TOL, (i, hh)
+
+
+@pytest.mark.parametrize("max_rows", [None, 16])
+@pytest.mark.parametrize("mode", ["q8_0", "q4_0"])
+def test_prefill_quant_kernels_match_xla_twin(mode, max_rows, monkeypatch):
+    """The fused chunked-prefill kernels (Pallas, interpret mode) against
+    their bounded-gather XLA twins, GQA and MLA, with padded chunk rows
+    (qpos = -1) and NULL-page tails.  ``max_rows=16`` splits each chunk's
+    query rows into two blocks per slot (the path whole-prompt chunks
+    take at real widths)."""
+    if max_rows:
+        monkeypatch.setattr(paged_attn, "_MAX_ROWS", max_rows)
+        jax.clear_caches()
+    rng = np.random.default_rng(11)
+    b, c, page_size, n_lp = 2, 8, 4, 4
+    hkv, rep, d, r, dr = 2, 2, 8, 16, 8
+    h = hkv * rep
+    n_pages = paged.RESERVED_PAGES + b * n_lp
+    bt = np.full((b, n_lp), paged.NULL_PAGE, np.int32)
+    pos_pool = np.full((n_pages, page_size), -1, np.int32)
+    horizon = (11, 6)                     # keys written up to these positions
+    nxt = paged.RESERVED_PAGES
+    for i in range(b):
+        for j in range(paged.pages_for(horizon[i] + 1, page_size)):
+            bt[i, j] = nxt
+            pos_pool[nxt] = np.arange(j * page_size, (j + 1) * page_size)
+            nxt += 1
+    qpos = np.stack([np.arange(4, 4 + c),                # lane 0: 4..11
+                     np.r_[np.arange(0, 7), -1]])        # lane 1: padded
+    qpos = jnp.asarray(qpos, jnp.int32)
+
+    def pools(*row_shape, mode=mode):
+        x = rng.normal(size=(n_pages, page_size, *row_shape))
+        return paged.quantize_rows(jnp.asarray(x, jnp.float32), mode)
+
+    kq, kd = pools(hkv, d)
+    vq, vd = pools(hkv, d)
+    q = jnp.asarray(rng.normal(size=(b, c, h, d)), jnp.float32)
+    gqa = {impl: np.asarray(paged_attn.paged_attn_prefill_quant(
+        q, kq, kd, vq, vd, jnp.asarray(pos_pool), jnp.asarray(bt), qpos,
+        mode=mode, impl=impl)) for impl in ("pallas", "xla")}
+    np.testing.assert_allclose(gqa["pallas"], gqa["xla"], rtol=TOL,
+                               atol=TOL)
+    assert np.all(gqa["pallas"][1, -1] == 0.0)           # padded row
+
+    cq, cd = pools(r, mode="q8_0")       # latents stay q8_0 under "dq"
+    rq, rd = pools(dr)
+    q_eff = jnp.asarray(rng.normal(size=(b, c, h, r)), jnp.float32)
+    q_rope = jnp.asarray(rng.normal(size=(b, c, h, dr)), jnp.float32)
+    mla = {impl: np.asarray(paged_attn.paged_mla_prefill_quant(
+        q_eff, q_rope, cq, cd, rq, rd, jnp.asarray(bt), qpos, scale=0.3,
+        latent_mode="q8_0", rope_mode=mode, impl=impl))
+        for impl in ("pallas", "xla")}
+    np.testing.assert_allclose(mla["pallas"], mla["xla"], rtol=TOL,
+                               atol=TOL)
+    assert np.all(mla["pallas"][1, -1] == 0.0)
+
+
+@pytest.mark.parametrize("chunk", [1, 3, 5])
+def test_prefill_kernel_chunk_split_bitwise(chunk):
+    """The Pallas prefill kernel enumerates pages in block-table order
+    whatever the chunk split, so each query row's output is bit for bit
+    the same whether it is attended in a split chunk or in the whole
+    prompt (the XLA twin matches only to float reassociation)."""
+    rng = np.random.default_rng(5)
+    b, c, page_size, hkv, rep, d = 2, 12, 4, 2, 2, 16
+    n_pages = paged.RESERVED_PAGES + b * 3
+    kq, kd = paged.quantize_rows(jnp.asarray(
+        rng.normal(size=(n_pages, page_size, hkv, d)), jnp.float32), "q4_0")
+    vq, vd = paged.quantize_rows(jnp.asarray(
+        rng.normal(size=(n_pages, page_size, hkv, d)), jnp.float32), "q4_0")
+    bt = paged.RESERVED_PAGES + np.arange(b * 3, dtype=np.int32).reshape(b, 3)
+    pos_pool = np.full((n_pages, page_size), -1, np.int32)
+    pos_pool[paged.RESERVED_PAGES:] = np.tile(
+        np.arange(3 * page_size).reshape(3, page_size), (b, 1))
+    q = jnp.asarray(rng.normal(size=(b, c, hkv * rep, d)), jnp.float32)
+    qpos = jnp.tile(jnp.arange(c, dtype=jnp.int32)[None], (b, 1))
+
+    def attend(lo, hi):
+        return np.asarray(paged_attn.paged_attn_prefill_quant(
+            q[:, lo:hi], kq, kd, vq, vd, jnp.asarray(pos_pool),
+            jnp.asarray(bt), qpos[:, lo:hi], mode="q4_0", impl="pallas"))
+
+    whole = attend(0, c)
+    for lo in range(0, c, chunk):
+        hi = min(c, lo + chunk)
+        assert np.array_equal(attend(lo, hi), whole[:, lo:hi]), (chunk, lo)
 
 
 # ---------------------------------------------------------------------------

@@ -76,3 +76,14 @@ def test_segment_cost_correction_arithmetic():
     segs = [SegmentCost("dec/G00", 79, 1e12, 1e9, 1e8, 0.0, {})]
     extra_flops = sum(s.flops * s.multiplier for s in segs)
     assert extra_flops == pytest.approx(79e12)
+
+
+def test_peaks_keyed_by_device_kind():
+    """The v5e peaks are found under the kind JAX reports for the chip,
+    and any other kind raises instead of borrowing them."""
+    v5e = hw.peaks("TPU v5 lite")
+    assert v5e.bf16_flops == 197e12 and v5e.hbm_bw == 819e9
+    assert hw.PEAK_FLOPS_BF16 == v5e.bf16_flops
+    for kind in ("cpu", "TPU v4", "TPU v6 lite"):
+        with pytest.raises(KeyError, match="no published peaks"):
+            hw.peaks(kind)

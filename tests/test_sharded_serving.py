@@ -21,8 +21,10 @@ qwen2-1.5b config (4 query heads, 2 KV heads):
 
 Bitwise parity holds because weights are only *stored* sharded — every
 contraction streams the full weight per device (see
-``Engine._constrained``) — and the head-split attention path is
-reduction-free across shards.
+``Engine._constrained``) — and the head-split attention output is
+gathered before ``o_proj``, so no contraction is summed across shards
+(``tests/test_tpu_compile.py`` holds the compiled TPU step to no
+all-reduce; the CPU's dots happen to give the same bits either way).
 """
 
 import warnings
@@ -265,6 +267,51 @@ def test_kernel_shard_map_matches_unsharded(spec):
         np.testing.assert_allclose(got, ref, atol=2e-6, rtol=2e-6)
     else:
         np.testing.assert_array_equal(got, ref)
+
+
+@needs_mesh
+@pytest.mark.parametrize("spec", ["4x2", "2x4"],
+                         ids=["head-split", "replicated-fallback"])
+def test_prefill_kernels_shard_map_match_unsharded(spec):
+    """The q8_0 chunked-prefill kernels (GQA and MLA) under shard_map —
+    the path Engine(mesh=...) takes with quantized pools, where a Mosaic
+    call outside shard_map cannot be partitioned — against the same
+    kernels on one device."""
+    rng = np.random.default_rng(1)
+    b, c, page_size, n_lp, hkv, rep, d, r, dr = 2, 6, 4, 3, 2, 2, 16, 16, 8
+    h = hkv * rep
+    n_pages = paged.RESERVED_PAGES + b * n_lp
+    bt = paged.RESERVED_PAGES + np.arange(b * n_lp, dtype=np.int32).reshape(
+        b, n_lp)
+    pos_pool = np.full((n_pages, page_size), -1, np.int32)
+    pos_pool[paged.RESERVED_PAGES:] = np.tile(
+        np.arange(n_lp * page_size).reshape(n_lp, page_size), (b, 1))
+    qpos = jnp.asarray(np.stack([np.arange(c), np.arange(2, 2 + c)]),
+                       jnp.int32)
+
+    def pool(*row):
+        return paged.quantize_rows(jnp.asarray(
+            rng.normal(size=(n_pages, page_size, *row)), jnp.float32), "q8_0")
+
+    kq, kd = pool(hkv, d)
+    vq, vd = pool(hkv, d)
+    cq, cd = pool(r)
+    rq, rd = pool(dr)
+    q = jnp.asarray(rng.normal(size=(b, c, h, d)), jnp.float32)
+    q_eff = jnp.asarray(rng.normal(size=(b, c, h, r)), jnp.float32)
+    q_rope = jnp.asarray(rng.normal(size=(b, c, h, dr)), jnp.float32)
+    mesh = mesh_from_spec(spec)
+    for m in (None, mesh):
+        got = (np.asarray(paged_attn.paged_attn_prefill_quant(
+                   q, kq, kd, vq, vd, jnp.asarray(pos_pool), jnp.asarray(bt),
+                   qpos, impl="pallas", interpret=True, mesh=m)),
+               np.asarray(paged_attn.paged_mla_prefill_quant(
+                   q_eff, q_rope, cq, cd, rq, rd, jnp.asarray(bt), qpos,
+                   scale=0.3, impl="pallas", interpret=True, mesh=m)))
+        if m is None:
+            ref = got
+    for g, want in zip(got, ref):
+        np.testing.assert_allclose(g, want, atol=2e-6, rtol=2e-6)
 
 
 @needs_mesh
