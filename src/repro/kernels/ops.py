@@ -40,14 +40,18 @@ def qmatmul(x: jax.Array, qt: QTensor, impl: str | None = None) -> jax.Array:
     """x: (..., K) [or (E, ..., K) matching qt's leading dims] -> (..., N)."""
     impl = impl or _DEFAULT_IMPL
     lead = qt.shape[:-2]
-    if impl == "pallas" and qt.fmt in PALLAS_MATMULS and not lead:
-        return PALLAS_MATMULS[qt.fmt](x, qt)
-    w = qt.dequantize(x.dtype)
-    if not lead:
-        return jnp.dot(x, w, preferred_element_type=jnp.float32).astype(x.dtype)
-    # batched (expert) weights: leading dims of x must match qt's
-    return jnp.einsum("...ck,...kn->...cn", x, w,
-                      preferred_element_type=jnp.float32).astype(x.dtype)
+    # every quantized weight product runs under one scope, so a device
+    # trace can sum the weight path's time (op_name ".../qmatmul/...")
+    with jax.named_scope("qmatmul"):
+        if impl == "pallas" and qt.fmt in PALLAS_MATMULS and not lead:
+            return PALLAS_MATMULS[qt.fmt](x, qt)
+        w = qt.dequantize(x.dtype)
+        if not lead:
+            return jnp.dot(x, w, preferred_element_type=jnp.float32
+                           ).astype(x.dtype)
+        # batched (expert) weights: leading dims of x must match qt's
+        return jnp.einsum("...ck,...kn->...cn", x, w,
+                          preferred_element_type=jnp.float32).astype(x.dtype)
 
 
 def qgather_columns(qt: QTensor, idx: jax.Array) -> jax.Array:

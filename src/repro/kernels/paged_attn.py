@@ -173,6 +173,20 @@ def _row_block(rows: int, cap: int) -> int:
     return rows
 
 
+def _kernel_name(*parts) -> str:
+    """The ``name=`` of a Pallas call, as a device trace shows it: family
+    and phase, table kind, then storage (a pool dtype, a quant mode, or
+    MLA's pair of modes), e.g. ``paged_attn_decode_full_bfloat16`` or
+    ``paged_mla_prefill_q8_0_q4_0``."""
+    words = ["paged"]
+    for p in parts:
+        if isinstance(p, tuple):
+            words += p
+        else:
+            words.append(p if isinstance(p, str) else jnp.dtype(p).name)
+    return "_".join(words)
+
+
 def _finish(o_ref, acc_ref, l_ref, nj: int):
     """Write the normalised accumulator on the last page step."""
 
@@ -443,6 +457,8 @@ def _attn_core(q, kv, pos_pool, block_table, pos, lane_pages, *,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((b, h, dv), jnp.float32),
             interpret=interpret,
+            name=_kernel_name("attn_decode", "ring" if window else "full",
+                              quant or kv_ops[0].dtype),
         )(block_table, pos, lane_pages, q, *kv_ops, _row_leaf(pos_pool))
 
     args = (block_table, pos, lane_pages, q, *kv, pos_pool)
@@ -664,6 +680,7 @@ def _mla_core(q_eff, q_rope, kv, block_table, pos, lane_pages, *,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((b, h, r), jnp.float32),
             interpret=interpret,
+            name=_kernel_name("mla_decode", quant or kv_ops[0].dtype),
         )(block_table, pos, lane_pages, q_eff, q_rope, *kv_ops)
 
     args = (block_table, pos, lane_pages, q_eff, q_rope, *kv)
@@ -1000,6 +1017,8 @@ def _attn_prefill_core(q, kv, pos_pool, block_table, qpos, *,
             out_shape=jax.ShapeDtypeStruct((b, hkv, c * rep, dv),
                                            jnp.float32),
             interpret=interpret,
+            name=_kernel_name("attn_prefill", "ring" if window else "full",
+                              quant),
         )(block_table, qg.reshape(b, hkv, c * rep, d),
           qrow.reshape(b, hkv, c * rep, 1), *kv_ops, _row_leaf(pos_pool))
         return o.reshape(b, hkv, c, rep, dv).transpose(0, 2, 1, 3, 4
@@ -1140,6 +1159,7 @@ def _mla_prefill_core(q_eff, q_rope, kv, block_table, qpos, *,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((b, rows, r), jnp.float32),
             interpret=interpret,
+            name=_kernel_name("mla_prefill", quant),
         )(block_table, q_eff.reshape(b, rows, r), q_rope.reshape(b, rows, dr),
           qrow.reshape(b, rows, 1), kv_ops[0], _col_leaf(kv_ops[1]),
           kv_ops[2], _col_leaf(kv_ops[3]))

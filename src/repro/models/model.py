@@ -50,10 +50,11 @@ class Model:
 
     # ------------------------------------------------------------------ embed
     def _embed_tokens(self, params, tokens):
-        x = embed(params["token_embd"], tokens, self.dtype)
-        if self.cfg.embed_scale:
-            x = x * jnp.asarray(
-                jnp.sqrt(self.cfg.d_model), x.dtype)
+        with jax.named_scope("embed"):
+            x = embed(params["token_embd"], tokens, self.dtype)
+            if self.cfg.embed_scale:
+                x = x * jnp.asarray(
+                    jnp.sqrt(self.cfg.d_model), x.dtype)
         return x
 
     def _fuse_frontend(self, params, batch):
@@ -139,8 +140,9 @@ class Model:
     def logits(self, params, hidden):
         cfg = self.cfg
         w = params["token_embd"] if cfg.tie_embeddings else params["output"]
-        out = linear(w, hidden)
-        out = softcap(out, cfg.logit_softcap)
+        with jax.named_scope("lm_head"):
+            out = linear(w, hidden)
+            out = softcap(out, cfg.logit_softcap)
         return out[..., : cfg.vocab_size]
 
     def forward(self, params, batch):

@@ -117,22 +117,24 @@ def decode_layer(cfg: ModelConfig, p: dict, layer: int, x: jax.Array,
                 ap = active[1] if use_ring else active[0]
                 ap = ap or None
             lp = lane_pages[tbl_kind] if lane_pages is not None else None
-            if cfg.mla:
+        with jax.named_scope("attn"):
+            if paged is not None and cfg.mla:
                 delta, cache_new = mla.mla_decode_paged(
                     p, cfg, x, cache, pos, bt, max_len=max_len, live=live,
                     kernel=kernel, active_pages=ap, lane_pages=lp,
                     kv_quant=kv_quant, mesh=mesh)
-            else:
+            elif paged is not None:
                 delta, cache_new = attention.attn_decode_paged(
-                    p, cfg, x, cache, pos, bt, local=local, max_len=max_len,
-                    live=live, kernel=kernel, active_pages=ap, lane_pages=lp,
-                    kv_quant=kv_quant, mesh=mesh)
-        elif cfg.mla:
-            delta, cache_new = mla.mla_decode(p, cfg, x, cache, pos,
-                                              live=live)
-        else:
-            delta, cache_new = attention.attn_decode(
-                p, cfg, x, cache, pos, local=local, live=live)
+                    p, cfg, x, cache, pos, bt, local=local,
+                    max_len=max_len, live=live, kernel=kernel,
+                    active_pages=ap, lane_pages=lp, kv_quant=kv_quant,
+                    mesh=mesh)
+            elif cfg.mla:
+                delta, cache_new = mla.mla_decode(p, cfg, x, cache, pos,
+                                                  live=live)
+            else:
+                delta, cache_new = attention.attn_decode(
+                    p, cfg, x, cache, pos, local=local, live=live)
         x = x + delta
     elif kind == "rglru":
         delta, cache_new = rglru.rglru_decode(p, cfg, x, cache, pos)
@@ -267,16 +269,18 @@ def prefill_chunk_layer(cfg: ModelConfig, p: dict, layer: int, x: jax.Array,
             if active is not None:
                 ap = active[1] if use_ring else active[0]
                 ap = ap or None
-        if cfg.mla:
-            delta, cache_new = mla.mla_prefill_chunk(
-                p, cfg, x, cache, positions, start, chunk_len,
-                max_len=max_len, block_table=bt, kv_quant=kv_quant,
-                kernel=kernel, active_pages=ap, mesh=mesh)
-        else:
-            delta, cache_new = attention.attn_prefill_chunk(
-                p, cfg, x, cache, positions, start, chunk_len, local=local,
-                max_len=max_len, block_table=bt, kv_quant=kv_quant,
-                kernel=kernel, active_pages=ap, mesh=mesh)
+        with jax.named_scope("attn"):
+            if cfg.mla:
+                delta, cache_new = mla.mla_prefill_chunk(
+                    p, cfg, x, cache, positions, start, chunk_len,
+                    max_len=max_len, block_table=bt, kv_quant=kv_quant,
+                    kernel=kernel, active_pages=ap, mesh=mesh)
+            else:
+                delta, cache_new = attention.attn_prefill_chunk(
+                    p, cfg, x, cache, positions, start, chunk_len,
+                    local=local, max_len=max_len, block_table=bt,
+                    kv_quant=kv_quant, kernel=kernel, active_pages=ap,
+                    mesh=mesh)
         x = x + delta
     elif kind == "rglru":
         delta, cache_new = rglru.rglru_prefill_chunk(

@@ -86,6 +86,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..checkpoint.fault_tolerance import straggler_threshold
 from ..models import paged, xlstm
@@ -127,6 +128,16 @@ def _default_swap_budget() -> int | None:
                    * SWAP_BUDGET_FRACTION)
     except (ValueError, OSError, AttributeError):
         return None
+
+
+def _named(fn, name: str):
+    """``fn`` under the name ``name``, which ``jax.jit`` gives the program
+    (``jit_<name>``); a ``functools.partial`` would lower as
+    ``jit__unknown``."""
+    def step(*args, **kwargs):
+        return fn(*args, **kwargs)
+    step.__name__ = step.__qualname__ = name
+    return step
 
 
 def _bucket_pages(n: int, cap: int) -> int:
@@ -200,6 +211,9 @@ class RequestStats:
     preemptions: int = 0         # times this request was swapped/kicked out
     # terminal status: "ok" | "timeout" | "cancelled" | "failed" | "shed"
     status: str = "ok"
+    # host time of each emitted token, seconds since the serve call began
+    # (one stamp per entry of ``Request.out``)
+    emit_s: list[float] = dataclasses.field(default_factory=list)
 
     @property
     def decode_tok_s(self) -> float:
@@ -291,6 +305,20 @@ class EngineStats:
     swap_disk_held_bytes: int = 0        # peak bytes held in spill files
     swap_held_end_bytes: int = 0         # host swap bytes still held at return
     swap_disk_end_bytes: int = 0         # spill bytes still held at return
+    # the serve loop's own measurements: iterations that dispatched a
+    # step program; host seconds blocked in the step readbacks, and the
+    # call's wall time less those; the same host seconds of each
+    # iteration that dispatched a program; decode tokens whose
+    # inter-token gap holds a whole chunked-prefill call (emitted by a
+    # lane that was live when an iteration that ran the chunk program
+    # began); new traces of the step programs during the call
+    loop_iterations: int = 0
+    device_wait_s: float = 0.0
+    host_s: float = 0.0
+    host_s_per_iteration: list[float] = dataclasses.field(
+        default_factory=list)
+    stalled_tokens: int = 0
+    step_programs_traced: int = 0
     # per-iteration scheduler snapshots, recorded after the admission
     # phase: {"queued": [(prio, seq, rid, pages_needed)], "active":
     # [(prio, seq, rid, pages_held)], "free_pages": int, "free_slots":
@@ -390,6 +418,15 @@ class EngineStats:
             f"concurrency max/mean: {self.max_concurrency}/"
             f"{self.mean_concurrency:.2f}",
         ]
+        if self.loop_iterations:
+            lines.append(
+                f"loop: {self.loop_iterations} iterations, host "
+                f"{self.host_s * 1e3 / self.loop_iterations:.2f} ms/iter "
+                f"(median "
+                f"{np.median(self.host_s_per_iteration) * 1e3:.2f}), "
+                f"device wait {self.device_wait_s:.2f}s, "
+                f"{self.stalled_tokens} tokens stalled behind a prefill "
+                f"chunk, {self.step_programs_traced} step programs traced")
         if self.mesh:
             lines.append(f"mesh: {self.mesh} (sharded weights + KV pools)")
         if self.page_size:
@@ -445,11 +482,15 @@ class EngineStats:
                     f"{cs['preemptions']:.0f} preemptions  [{st}]")
         for r in sorted(self.requests, key=lambda r: r.rid):
             tag = "" if r.status == "ok" else f"  [{r.status}]"
+            itl = ""
+            if len(r.emit_s) > 1:
+                p50, p95 = np.percentile(np.diff(r.emit_s), [50, 95]) * 1e3
+                itl = f"  itl p50/p95 {p50:.1f}/{p95:.1f}ms"
             lines.append(
                 f"  req {r.rid}: wait {r.queue_wait_s * 1e3:.1f}ms  "
                 f"prefill {r.prefill_s * 1e3:.1f}ms  "
                 f"decode {r.decode_tokens} tok @ {r.decode_tok_s:.1f} tok/s"
-                f"{tag}")
+                f"{itl}{tag}")
         return "\n".join(lines)
 
 
@@ -782,6 +823,12 @@ class Engine:
         if self.mesh is not None:
             decode_paged = self._constrained(decode_paged)
             chunk_fn = self._constrained(chunk_fn)
+        # the step programs carry stable names (jit_engine_decode, ...) so
+        # a device trace can tell them apart
+        decode_paged = _named(decode_paged, "engine_decode")
+        chunk_fn = _named(chunk_fn, "engine_prefill_chunk")
+        scrub = _named(scrub, "engine_scrub")
+        scrub_all = _named(scrub_all, "engine_scrub_all")
         if jit:
             self._decode = jax.jit(model.decode_step)
             # active_pages is a static (n_full, n_ring) page bound for the
@@ -798,6 +845,10 @@ class Engine:
             self._chunk = chunk_fn
             self._scrub = scrub
             self._scrub_all = scrub_all
+        # the jitted step programs as built (callers may wrap the
+        # attributes); serve counts their new traces
+        self._step_programs = (self._decode, self._decode_paged,
+                               self._chunk, self._scrub, self._scrub_all)
         if self.quant_probe:
             # shadow f32 path: same steps, same block tables, kv_quant=None
             probe_decode = partial(model.decode_step_paged,
@@ -807,6 +858,8 @@ class Engine:
             probe_chunk = partial(model.prefill_chunk, max_len=max_len,
                                   page_size=page_size, kv_quant=None,
                                   kernel=self.kernel)
+            probe_decode = _named(probe_decode, "engine_probe_decode")
+            probe_chunk = _named(probe_chunk, "engine_probe_chunk")
             if jit:
                 probe_decode = jax.jit(probe_decode,
                                        static_argnames=("active_pages",))
@@ -933,6 +986,7 @@ class Engine:
         way (``EngineStats.fault_log`` records what actually landed).
         """
         t_start = time.perf_counter()
+        traced_at_start = self._programs_traced()
         stats = EngineStats()
         stats.scheduler = self.scheduler
         preempt = self.scheduler == "preempt"
@@ -1009,10 +1063,7 @@ class Engine:
         def pending() -> bool:
             return bool(pqueue) if preempt else bool(queue)
 
-        n_full = paged.pages_for(self.max_len, P) if (use_paged
-                                                      and self._has_full) else 0
-        n_ring = paged.pages_for(self._ring_len, P) if (use_paged
-                                                        and self._has_ring) else 0
+        n_full, n_ring = self._table_pages() if use_paged else (0, 0)
         if use_paged:
             num_pages = self.pool_pages(slots)
             pool = PagePool(num_pages)
@@ -1294,14 +1345,15 @@ class Engine:
                         stacklevel=2)
             if not restart:
                 ids = lane.pages_full + lane.pages_ring
-                pool_rows = {
-                    k: jax.device_get(paged.extract_pages(
-                        cache[k], ids, axis=pool_axis))
-                    for k in pool_leaves} if ids else {}
-                slot_rows = {
-                    k: jax.device_get(cache[k][:, s] if pool_axis
-                                      else cache[k][s])
-                    for k in slot_leaves}
+                with TraceAnnotation("engine.swap_out"):
+                    pool_rows = {
+                        k: jax.device_get(paged.extract_pages(
+                            cache[k], ids, axis=pool_axis))
+                        for k in pool_leaves} if ids else {}
+                    slot_rows = {
+                        k: jax.device_get(cache[k][:, s] if pool_axis
+                                          else cache[k][s])
+                        for k in slot_leaves}
                 sw = _Swapped(
                     req=req, seq=seq, tok=lane.tok, pos=lane.pos,
                     n_out=lane.n_out, req_key=lane.req_key,
@@ -1342,7 +1394,7 @@ class Engine:
                 stats.swap_out_bytes += sw.nbytes
                 item: Any = sw
             else:
-                req.out = []
+                req.out, req.stats.emit_s = [], []
                 item = req
             release(lane, s)
             requeue(item, req.priority, seq)
@@ -1475,8 +1527,10 @@ class Engine:
                     terminate(req, doomed(req, now),
                               queue_wait=now - t_start)
 
-        while pending() or any(s.state != _FREE for s in lanes):
-            it += 1
+        def admit() -> None:
+            """Reap cancelled and past-deadline requests, fire the plan's
+            scheduled faults, and claim free slots for queued requests."""
+            nonlocal alloc_ok
             # scheduled cancellations fire as real cancel() calls — the
             # deterministic chaos path for mid-flight cancellation
             while True:
@@ -1543,10 +1597,11 @@ class Engine:
                             else:
                                 drop_item(item)
                                 stats.swap_restarts += 1
-                                req.out = []
+                                req.out, req.stats.emit_s = [], []
                                 requeue(req, prio, seq)
                             continue
-                        swap_in(lane, s, item, seq)
+                        with TraceAnnotation("engine.swap_in"):
+                            swap_in(lane, s, item, seq)
                         continue
                     req.out = []  # (re)start: output accumulates from zero
                     if req.stats is None:
@@ -1555,6 +1610,7 @@ class Engine:
                             queue_wait_s=now - enq_t[seq])
                     else:  # restarted prefill: accumulate the re-queue wait
                         req.stats.queue_wait_s += now - enq_t[seq]
+                        req.stats.emit_s = []
                     if use_paged:
                         bt_full[s, :] = paged.NULL_PAGE
                         bt_ring[s, :] = paged.NULL_PAGE
@@ -1615,197 +1671,238 @@ class Engine:
                                       if isinstance(e[3], _Swapped)),
                 })
 
+        def emit(req: Request, tok: int) -> None:
+            req.out.append(tok)
+            req.stats.emit_s.append(time.perf_counter() - t_start)
+
+        def iteration() -> None:
+            """One pass of the loop, each phase inside its own ``engine.*``
+            host span: admission, one batched prefill chunk over the
+            admitting lanes, one batched decode step over all slots, then
+            emission and retirement."""
+            nonlocal it, cache, shadow, probe_gap
+            it += 1
+            # requests decoding as the iteration begins: the gap before
+            # their next token holds this iteration's chunk call, if any
+            live_at_start = {id(l.req) for l in lanes if l.live}
+            with TraceAnnotation("engine.admit"):
+                admit()
+
             # -- one batched prefill chunk over all admitting lanes ----------
             prefilling = [s for s, l in enumerate(lanes)
                           if l.state == _PREFILL]
+            chunk_ran = False
             if prefilling:
-                toks = np.zeros((slots, C), np.int32)
-                start = np.zeros(slots, np.int32)
-                clen = np.zeros(slots, np.int32)
-                for s in prefilling:
-                    lane = lanes[s]
-                    if lane.state != _PREFILL:
-                        continue  # evicted by an earlier lane's free_up
-                    prompt = lane.req.prompt
-                    n = min(C, len(prompt) - lane.prefill_pos)
-                    if not ensure_pages(lane, s, lane.prefill_pos,
-                                        lane.prefill_pos + n):
-                        continue  # preempted itself: requeued, skip chunk
-                    toks[s, :n] = prompt[lane.prefill_pos:lane.prefill_pos + n]
-                    start[s] = lane.prefill_pos
-                    clen[s] = n
-                for s in prefilling:
-                    if lanes[s].state != _PREFILL:
-                        clen[s] = 0  # evicted after its chunk was assembled
-            if prefilling and clen.any():
-                kwargs = {"block_tables": tables()} if use_paged else {}
-                logits, cache = self._chunk(
-                    self.params, cache, jnp.asarray(toks), jnp.asarray(start),
-                    jnp.asarray(clen), **kwargs)
-                if use_paged and self.quant_probe:
-                    _, shadow = self._probe_chunk(
-                        self.params, shadow, jnp.asarray(toks),
-                        jnp.asarray(start), jnp.asarray(clen), **kwargs)
-                stats.prefill_iterations += 1
-                first_toks = first_bad = None
-                for s in prefilling:
-                    lane = lanes[s]
-                    if lane.state != _PREFILL or not clen[s]:
-                        continue
-                    lane.prefill_pos += int(clen[s])
-                    if lane.prefill_pos < len(lane.req.prompt):
-                        continue  # more chunks to stream
-                    if first_toks is None:
-                        # non-finite-logit flags ride the same transfer
-                        # as the sampled tokens (quarantine detector)
-                        bad = ~jnp.all(
-                            jnp.isfinite(logits.astype(jnp.float32)),
-                            axis=-1)
-                        if self.sampler.greedy:
-                            cand = jnp.argmax(logits, axis=-1)
-                        else:
-                            keys = jnp.stack(
-                                [stream_key(l.req_key, 0)
-                                 if l.req_key is not None
-                                 else jnp.zeros(2, jnp.uint32) for l in lanes])
-                            cand = sample_per_slot(logits, keys, self.sampler)
-                        packed = np.asarray(jnp.concatenate(
-                            [cand.astype(jnp.int32),
-                             bad.astype(jnp.int32)]))
-                        first_toks, first_bad = (packed[:slots],
-                                                 packed[slots:])
-                    req = lane.req
-                    # prefill wall time = admission -> first token (chunk
-                    # compute + any decode iterations interleaved between
-                    # this prompt's chunks); first_toks forced the device
-                    req.stats.prefill_s = (time.perf_counter() - t_start
-                                           - req.stats.queue_wait_s)
-                    if first_bad[s]:
-                        # non-finite prefill logits: quarantine only this
-                        # lane (pages scrubbed + freed, status="failed")
-                        stats.nan_quarantines += 1
-                        release(lane, s)
-                        terminate(req, "failed")
-                        continue
-                    tok = int(first_toks[s])
-                    req.out.append(tok)
-                    budget = min(req.max_new, self.max_len - len(req.prompt))
-                    if tok == self.eos_id or len(req.out) >= budget:
-                        rst = req.stats
-                        finish(req, rst)   # completed on the prefill token
-                        release(lane, s)
-                        continue
-                    lane.state = _LIVE
-                    lane.tok, lane.pos, lane.n_out = tok, len(req.prompt), 1
-
-            # decode-time page allocation may itself preempt lanes under
-            # scheduler="preempt", so allocate BEFORE freezing the live set
-            if alloc_decode_pages(np.array(
-                    [s for s, l in enumerate(lanes) if l.live], np.int32)):
-                # allocator fault: the missing pages are exactly this
-                # step's write targets, so the whole decode step stalls
-                # one iteration — pure latency, no lane state advances,
-                # outputs stay bitwise identical
-                continue
-            live = [s for s in lanes if s.live]
-            if not live:
-                continue
-            if prefilling:
-                stats.overlap_iterations += 1
-
-            # -- one jit'd batched decode step over ALL slots ----------------
-            stats.decode_iterations += 1
-            stats.live_per_iteration.append(len(live))
-            stats.live_tokens_per_iteration.append(
-                sum(l.pos + 1 for l in lanes if l.live)
-                + sum(l.prefill_pos for l in lanes if l.state == _PREFILL))
-            if use_paged:
-                stats.pages_in_use_per_iteration.append(pool.in_use)
-            if plan is not None and use_paged:
-                # corrupt_page faults poison one held page of the target
-                # lane across every payload pool leaf (pos rows stay —
-                # the page must still LOOK valid): the lane's next logits
-                # go non-finite and the quarantine below must contain the
-                # blast radius to that lane alone
-                for s, lane in enumerate(lanes):
-                    if not lane.live or not (lane.pages_full
-                                             or lane.pages_ring):
-                        continue
-                    f = fire("corrupt_page", lane.req.rid)
-                    if f is None:
-                        continue
-                    stats.pages_corrupted += 1
-                    pid = (lane.pages_full or lane.pages_ring)[0]
-                    upd = {}
-                    for k in pool_leaves:
-                        if k.endswith("/pos"):
+                with TraceAnnotation("engine.prefill.prepare"):
+                    toks = np.zeros((slots, C), np.int32)
+                    start = np.zeros(slots, np.int32)
+                    clen = np.zeros(slots, np.int32)
+                    for s in prefilling:
+                        lane = lanes[s]
+                        if lane.state != _PREFILL:
+                            continue  # evicted by an earlier lane's free_up
+                        prompt = lane.req.prompt
+                        lo = lane.prefill_pos
+                        n = min(C, len(prompt) - lo)
+                        if not ensure_pages(lane, s, lo, lo + n):
+                            continue  # preempted itself: requeued, skip
+                        toks[s, :n] = prompt[lo:lo + n]
+                        start[s] = lo
+                        clen[s] = n
+                    for s in prefilling:
+                        if lanes[s].state != _PREFILL:
+                            clen[s] = 0  # evicted after its chunk was built
+                    chunk_ran = bool(clen.any())
+                    if chunk_ran:
+                        chunk_in = (jnp.asarray(toks), jnp.asarray(start),
+                                    jnp.asarray(clen))
+                        kwargs = ({"block_tables": tables()} if use_paged
+                                  else {})
+            if chunk_ran:
+                with TraceAnnotation("engine.prefill.dispatch"):
+                    logits, cache = self._chunk(self.params, cache,
+                                                *chunk_in, **kwargs)
+                    if use_paged and self.quant_probe:
+                        _, shadow = self._probe_chunk(
+                            self.params, shadow, *chunk_in, **kwargs)
+                    stats.prefill_iterations += 1
+                    stats.loop_iterations += 1
+                with TraceAnnotation("engine.prefill.first_token"):
+                    first_toks = first_bad = None
+                    for s in prefilling:
+                        lane = lanes[s]
+                        if lane.state != _PREFILL or not clen[s]:
                             continue
-                        v = cache[k]
-                        if jnp.issubdtype(v.dtype, jnp.floating):
-                            fill = jnp.asarray(
-                                f.value if f.value is not None
-                                else jnp.inf, v.dtype)
-                        else:   # q8 int8 payloads: scales carry the inf
-                            fill = jnp.asarray(jnp.iinfo(v.dtype).max,
-                                               v.dtype)
-                        upd[k] = (v.at[:, pid].set(fill) if pool_axis
-                                  else v.at[pid].set(fill))
-                    cache = dict(cache, **upd)
-            toks = jnp.asarray([s.tok for s in lanes], jnp.int32)
-            pos = jnp.asarray([s.pos if s.live else 0 for s in lanes],
-                              jnp.int32)
-            live_mask = jnp.asarray([s.live for s in lanes])
-            t0 = time.perf_counter()
-            lat = fire("latency")
-            if lat is not None:
-                # injected step-latency spike, inside the timed window so
-                # the step watchdog sees it like a real stall
-                time.sleep(lat.value if lat.value is not None else 0.02)
-            if use_paged:
-                active = None
-                lane_pages = None
-                if self.kernel == "fused":
-                    # bucketed live horizon: the fused kernels' page loops
-                    # (and hence decode bandwidth) follow live tokens, and
-                    # power-of-two buckets bound the number of jit traces
-                    horizon = max(l.pos + 1 for l in lanes if l.live)
-                    active = (
-                        _bucket_pages(paged.pages_for(horizon, P), n_full),
-                        _bucket_pages(
-                            paged.pages_for(min(horizon, self._ring_len), P),
-                            n_ring))
-                    # per-lane page counts: the kernels clamp each lane's
-                    # page loop to its OWN live pages, so a short lane's
-                    # HBM reads don't scale with the longest lane in the
-                    # batch (free lanes charge their single clamped read)
-                    lf = np.array(
-                        [min(paged.pages_for(l.pos + 1, P), active[0])
-                         if l.live else 1 for l in lanes], np.int32)
-                    lr = np.array(
-                        [min(paged.pages_for(min(l.pos + 1, self._ring_len),
-                                             P), active[1])
-                         if l.live else 1 for l in lanes], np.int32)
-                    lane_pages = {"full": jnp.asarray(lf),
-                                  "ring": jnp.asarray(lr)}
-                    if n_full:
-                        stats.decode_kv_bytes += (int(lf.sum())
-                                                  * self._full_page_bytes)
-                    if n_ring:
-                        stats.decode_kv_bytes += (int(lr.sum())
-                                                  * self._ring_page_bytes)
+                        lane.prefill_pos += int(clen[s])
+                        if lane.prefill_pos < len(lane.req.prompt):
+                            continue  # more chunks to stream
+                        if first_toks is None:
+                            keys = None if self.sampler.greedy else (
+                                jnp.stack([stream_key(l.req_key, 0)
+                                           if l.req_key is not None
+                                           else jnp.zeros(2, jnp.uint32)
+                                           for l in lanes]))
+                            t_wait = time.perf_counter()
+                            first_toks, first_bad = self._readback(logits,
+                                                                   keys)
+                            stats.device_wait_s += (time.perf_counter()
+                                                    - t_wait)
+                        req = lane.req
+                        # prefill wall time = admission -> first token
+                        # (chunk compute + any decode iterations
+                        # interleaved between this prompt's chunks);
+                        # first_toks forced the device
+                        req.stats.prefill_s = (time.perf_counter() - t_start
+                                               - req.stats.queue_wait_s)
+                        if first_bad[s]:
+                            # non-finite prefill logits: quarantine only
+                            # this lane (pages scrubbed + freed,
+                            # status="failed")
+                            stats.nan_quarantines += 1
+                            release(lane, s)
+                            terminate(req, "failed")
+                            continue
+                        tok = int(first_toks[s])
+                        emit(req, tok)
+                        budget = min(req.max_new,
+                                     self.max_len - len(req.prompt))
+                        if tok == self.eos_id or len(req.out) >= budget:
+                            finish(req, req.stats)  # done on its first token
+                            release(lane, s)
+                            continue
+                        lane.state = _LIVE
+                        lane.tok, lane.pos, lane.n_out = (tok,
+                                                          len(req.prompt), 1)
+
+            with TraceAnnotation("engine.decode.prepare"):
+                # decode-time page allocation may itself preempt lanes under
+                # scheduler="preempt", so allocate BEFORE freezing the live
+                # set
+                if alloc_decode_pages(np.array(
+                        [s for s, l in enumerate(lanes) if l.live], np.int32)):
+                    # allocator fault: the missing pages are exactly this
+                    # step's write targets, so the whole decode step stalls
+                    # one iteration — pure latency, no lane state advances,
+                    # outputs stay bitwise identical
+                    return
+                live = [s for s in lanes if s.live]
+                if not live:
+                    return
+                if prefilling:
+                    stats.overlap_iterations += 1
+
+                # -- one jit'd batched decode step over ALL slots ------------
+                stats.decode_iterations += 1
+                stats.live_per_iteration.append(len(live))
+                stats.live_tokens_per_iteration.append(
+                    sum(l.pos + 1 for l in lanes if l.live)
+                    + sum(l.prefill_pos for l in lanes
+                          if l.state == _PREFILL))
+                if use_paged:
+                    stats.pages_in_use_per_iteration.append(pool.in_use)
+                if plan is not None and use_paged:
+                    # corrupt_page faults poison one held page of the
+                    # target lane across every payload pool leaf (pos rows
+                    # stay — the page must still LOOK valid): the lane's
+                    # next logits go non-finite and the quarantine below
+                    # must contain the blast radius to that lane alone
+                    for s, lane in enumerate(lanes):
+                        if not lane.live or not (lane.pages_full
+                                                 or lane.pages_ring):
+                            continue
+                        f = fire("corrupt_page", lane.req.rid)
+                        if f is None:
+                            continue
+                        stats.pages_corrupted += 1
+                        pid = (lane.pages_full or lane.pages_ring)[0]
+                        upd = {}
+                        for k in pool_leaves:
+                            if k.endswith("/pos"):
+                                continue
+                            v = cache[k]
+                            if jnp.issubdtype(v.dtype, jnp.floating):
+                                fill = jnp.asarray(
+                                    f.value if f.value is not None
+                                    else jnp.inf, v.dtype)
+                            else:   # q8 int8 payloads: scales carry the inf
+                                fill = jnp.asarray(jnp.iinfo(v.dtype).max,
+                                                   v.dtype)
+                            upd[k] = (v.at[:, pid].set(fill) if pool_axis
+                                      else v.at[pid].set(fill))
+                        cache = dict(cache, **upd)
+                toks = jnp.asarray([s.tok for s in lanes], jnp.int32)
+                pos = jnp.asarray([s.pos if s.live else 0 for s in lanes],
+                                  jnp.int32)
+                live_mask = jnp.asarray([s.live for s in lanes])
+                t0 = time.perf_counter()
+                if use_paged:
+                    active = None
+                    lane_pages = None
+                    if self.kernel == "fused":
+                        # bucketed live horizon: the fused kernels' page
+                        # loops (and hence decode bandwidth) follow live
+                        # tokens, and power-of-two buckets bound the
+                        # number of jit traces
+                        active = self._active_pages(
+                            max(l.pos + 1 for l in lanes if l.live))
+                        # per-lane page counts: the kernels clamp each
+                        # lane's page loop to its OWN live pages, so a
+                        # short lane's HBM reads don't scale with the
+                        # longest lane in the batch (free lanes charge
+                        # their single clamped read)
+                        lf = np.array(
+                            [min(paged.pages_for(l.pos + 1, P), active[0])
+                             if l.live else 1 for l in lanes], np.int32)
+                        lr = np.array(
+                            [min(paged.pages_for(
+                                min(l.pos + 1, self._ring_len), P),
+                                 active[1])
+                             if l.live else 1 for l in lanes], np.int32)
+                        lane_pages = {"full": jnp.asarray(lf),
+                                      "ring": jnp.asarray(lr)}
+                        if n_full:
+                            stats.decode_kv_bytes += (
+                                int(lf.sum()) * self._full_page_bytes)
+                        if n_ring:
+                            stats.decode_kv_bytes += (
+                                int(lr.sum()) * self._ring_page_bytes)
+                    else:
+                        stats.decode_kv_bytes += slots * (
+                            n_full * self._full_page_bytes
+                            + n_ring * self._ring_page_bytes)
+                    bt = tables()
                 else:
-                    stats.decode_kv_bytes += slots * (
-                        n_full * self._full_page_bytes
-                        + n_ring * self._ring_page_bytes)
-                logits, cache = self._decode_paged(
-                    self.params, cache, toks, pos, tables(), live=live_mask,
-                    active_pages=active, lane_pages=lane_pages)
-                if self.quant_probe:
+                    # charge only the attn/MLA cache reads (recurrent
+                    # passthrough excluded) so kvB/tok is comparable with
+                    # the paged modes, which only ever charge positional
+                    # pools
+                    stats.decode_kv_bytes += dense_kv_read
+
+            with TraceAnnotation("engine.decode.dispatch"):
+                lat = fire("latency")
+                if lat is not None:
+                    # injected step-latency spike, inside the timed window
+                    # so the step watchdog sees it like a real stall
+                    time.sleep(lat.value if lat.value is not None else 0.02)
+                if use_paged:
+                    logits, cache = self._decode_paged(
+                        self.params, cache, toks, pos, bt, live=live_mask,
+                        active_pages=active, lane_pages=lane_pages)
+                else:
+                    logits, cache = self._decode(self.params, cache, toks,
+                                                 pos, live=live_mask)
+                stats.decoded_tokens += len(live)
+                if not chunk_ran:
+                    stats.loop_iterations += 1
+
+            with TraceAnnotation("engine.decode.sync"):
+                t_wait = time.perf_counter()
+                if use_paged and self.quant_probe:
                     # shadow step on the f32 pools, teacher-forced with the
                     # quantized run's tokens: the per-lane gap isolates
                     # the cache quantization error at identical context
                     ref, shadow = self._probe_decode(
-                        self.params, shadow, toks, pos, tables(),
+                        self.params, shadow, toks, pos, bt,
                         live=live_mask, active_pages=active,
                         lane_pages=lane_pages)
                     gap = np.asarray(
@@ -1818,77 +1915,75 @@ class Engine:
                     probe_gap = np.where(alive, np.maximum(probe_gap, gap),
                                          probe_gap)
                     stats.quant_probe_steps += 1
-            else:
-                # charge only the attn/MLA cache reads (recurrent
-                # passthrough excluded) so kvB/tok is comparable with the
-                # paged modes, which only ever charge positional pools
-                stats.decode_kv_bytes += dense_kv_read
-                logits, cache = self._decode(self.params, cache, toks, pos,
-                                             live=live_mask)
-            stats.decoded_tokens += len(live)
-            if plan is not None:
-                # nan_logits faults overwrite the target lane's logits
-                # row before sampling — the detector below must catch it
+                if plan is not None:
+                    # nan_logits faults overwrite the target lane's logits
+                    # row before sampling — the detector must catch it
+                    for s, lane in enumerate(lanes):
+                        if not lane.live:
+                            continue
+                        f = fire("nan_logits", lane.req.rid)
+                        if f is not None:
+                            logits = logits.at[s].set(jnp.asarray(
+                                f.value if f.value is not None else jnp.nan,
+                                logits.dtype))
+                keys = None if self.sampler.greedy else jnp.stack(
+                    [stream_key(l.req_key, l.n_out) if l.live
+                     else jnp.zeros(2, jnp.uint32) for l in lanes])
+                # the step's one host sync; doubles as the timing barrier
+                host_tok, host_bad = self._readback(logits, keys)
+                stats.device_wait_s += time.perf_counter() - t_wait
+
+            with TraceAnnotation("engine.emit"):
+                dt = time.perf_counter() - t0
+                # step watchdog: HeartbeatMonitor's straggler rule over the
+                # engine's own recent decode steps
+                step_times.append(dt)
+                del step_times[:-WATCHDOG_WINDOW]
+                if len(step_times) >= WATCHDOG_MIN_SAMPLES:
+                    cut = straggler_threshold(step_times[:-1],
+                                              self.watchdog_factor)
+                    if dt > cut > 0:
+                        stats.slow_steps += 1
+
+                # -- emit + retire ------------------------------------------
                 for s, lane in enumerate(lanes):
                     if not lane.live:
                         continue
-                    f = fire("nan_logits", lane.req.rid)
-                    if f is not None:
-                        logits = logits.at[s].set(jnp.asarray(
-                            f.value if f.value is not None else jnp.nan,
-                            logits.dtype))
-            if self.sampler.greedy:
-                next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            else:
-                keys = jnp.stack(
-                    [stream_key(l.req_key, l.n_out) if l.live
-                     else jnp.zeros(2, jnp.uint32) for l in lanes])
-                next_tok = sample_per_slot(logits, keys, self.sampler)
-            # per-lane non-finite-logit flags ride the same transfer as
-            # the sampled tokens (quarantine detector, always on)
-            bad = ~jnp.all(jnp.isfinite(logits.astype(jnp.float32)),
-                           axis=-1)
-            # one materialisation per step; doubles as the timing barrier
-            # repro-lint: disable=host-sync-in-hot-path (honest step timing)
-            packed = np.asarray(jax.block_until_ready(jnp.concatenate(
-                [next_tok.astype(jnp.int32), bad.astype(jnp.int32)])))
-            host_tok, host_bad = packed[:slots], packed[slots:]
-            dt = time.perf_counter() - t0
-            # step watchdog: HeartbeatMonitor's straggler rule over the
-            # engine's own recent decode steps
-            step_times.append(dt)
-            del step_times[:-WATCHDOG_WINDOW]
-            if len(step_times) >= WATCHDOG_MIN_SAMPLES:
-                cut = straggler_threshold(step_times[:-1],
-                                          self.watchdog_factor)
-                if dt > cut > 0:
-                    stats.slow_steps += 1
+                    req = lane.req
+                    rst = req.stats
+                    rst.decode_s += dt
+                    if host_bad[s]:
+                        # non-finite logits: quarantine ONLY this lane —
+                        # pages scrubbed + freed, status="failed"; every
+                        # other lane decodes on untouched
+                        stats.nan_quarantines += 1
+                        release(lane, s)
+                        terminate(req, "failed")
+                        continue
+                    rst.decode_tokens += 1
+                    tok = int(host_tok[s])
+                    emit(req, tok)
+                    if chunk_ran and id(req) in live_at_start:
+                        stats.stalled_tokens += 1
+                    lane.tok, lane.pos, lane.n_out = tok, lane.pos + 1, \
+                        lane.n_out + 1
+                    budget = min(req.max_new,
+                                 self.max_len - len(req.prompt))
+                    if (tok == self.eos_id or lane.n_out >= budget
+                            or lane.pos + 1 >= self.max_len):
+                        finish(req, rst)
+                        release(lane, s)
 
-            # -- emit + retire ----------------------------------------------
-            for s, lane in enumerate(lanes):
-                if not lane.live:
-                    continue
-                req = lane.req
-                rst = req.stats
-                rst.decode_s += dt
-                if host_bad[s]:
-                    # non-finite logits: quarantine ONLY this lane —
-                    # pages scrubbed + freed, status="failed"; every
-                    # other lane decodes on untouched
-                    stats.nan_quarantines += 1
-                    release(lane, s)
-                    terminate(req, "failed")
-                    continue
-                rst.decode_tokens += 1
-                tok = int(host_tok[s])
-                req.out.append(tok)
-                lane.tok, lane.pos, lane.n_out = tok, lane.pos + 1, \
-                    lane.n_out + 1
-                budget = min(req.max_new, self.max_len - len(req.prompt))
-                if (tok == self.eos_id or lane.n_out >= budget
-                        or lane.pos + 1 >= self.max_len):
-                    finish(req, rst)
-                    release(lane, s)
+        alloc_ok = True
+        while pending() or any(s.state != _FREE for s in lanes):
+            t_iter, waited = time.perf_counter(), stats.device_wait_s
+            dispatched = stats.loop_iterations
+            with TraceAnnotation("engine.iteration"):
+                iteration()
+            if stats.loop_iterations > dispatched:
+                stats.host_s_per_iteration.append(
+                    time.perf_counter() - t_iter
+                    - (stats.device_wait_s - waited))
 
         if use_paged:
             stats.peak_pages = pool.peak_in_use
@@ -1905,6 +2000,9 @@ class Engine:
         # finished rids must not leak into the next serve call
         self._cancel_rids.clear()
         stats.wall_s = time.perf_counter() - t_start
+        stats.host_s = stats.wall_s - stats.device_wait_s
+        stats.step_programs_traced = (self._programs_traced()
+                                      - traced_at_start)
         self.last_stats = stats
         return done
 
@@ -1985,6 +2083,79 @@ class Engine:
         return (paged.pages_for(self.max_len, P) if self._has_full else 0,
                 paged.pages_for(self._ring_len, P) if self._has_ring else 0)
 
+    def _active_pages(self, horizon: int) -> tuple[int, int]:
+        """The fused decode kernels' static (full, ring) page bound for a
+        batch whose longest lane holds ``horizon`` tokens: its live pages
+        rounded up to a power of two (one trace per bucket)."""
+        n_full, n_ring = self._table_pages()
+        P = self.page_size
+        return (_bucket_pages(paged.pages_for(horizon, P), n_full),
+                _bucket_pages(paged.pages_for(min(horizon, self._ring_len),
+                                              P), n_ring))
+
+    def _programs_traced(self) -> int:
+        """Traces the jitted step programs hold (0 with ``jit=False``)."""
+        return sum(f._cache_size() for f in self._step_programs
+                   if hasattr(f, "_cache_size"))
+
+    def _readback(self, logits, keys=None) -> tuple[np.ndarray, np.ndarray]:
+        """Sample one token per slot from ``logits`` — greedy, or from each
+        slot's stream ``keys`` — and flag the slots whose logits are not
+        all finite (the quarantine detector).  Both reach the host in one
+        transfer, which waits for the step that made ``logits``.  It runs
+        eagerly: its programs (``jit__argmax``, ``jit_isfinite``, ...) are
+        found in a trace by name, and carry no named scope."""
+        if keys is None:
+            tok = jnp.argmax(logits, axis=-1)
+        else:
+            tok = sample_per_slot(logits, keys, self.sampler)
+        bad = ~jnp.all(jnp.isfinite(logits.astype(jnp.float32)), axis=-1)
+        # repro-lint: disable=host-sync-in-hot-path (the step's one sync)
+        packed = np.asarray(jnp.concatenate(
+            [tok.astype(jnp.int32), bad.astype(jnp.int32)]))
+        n = logits.shape[0]
+        return packed[:n], packed[n:]
+
+    def warm_up(self, slots: int, max_tokens: int) -> None:
+        """Compile, before serving, every step program a :meth:`serve`
+        over ``slots`` lanes runs while no request holds more than
+        ``max_tokens`` tokens (prompt and answer): one chunked-prefill
+        call, the decode step at every page bucket those lengths reach
+        (by the serve loop's own bucketing), the sampling readback and the
+        page scrub.  A serve within those bounds then traces no new step
+        program (``EngineStats.step_programs_traced`` stays 0).  Requires
+        ``jit=True`` and the paged cache; the fault plan's full scrub and
+        the ``quant_probe`` shadow steps are not warmed."""
+        avals, tables = self._abstract_step_inputs(self._chunk, slots)
+        cache = {k: jnp.zeros(a.shape, a.dtype, device=a.sharding)
+                 for k, a in avals.items()}
+        tables = {k: jnp.zeros(a.shape, a.dtype) for k, a in tables.items()}
+        zeros = jnp.zeros((slots,), jnp.int32)
+        keys = (None if self.sampler.greedy
+                else jnp.zeros((slots, 2), jnp.uint32))
+        logits, cache = self._chunk(
+            self.params, cache, jnp.zeros((slots, self.prefill_chunk),
+                                          jnp.int32),
+            zeros, zeros, block_tables=tables)
+        self._readback(logits, keys)
+        buckets, lane_pages = [None], None
+        if self.kernel == "fused":
+            buckets = sorted({self._active_pages(t) for t in
+                              range(1, min(max_tokens, self.max_len) + 1)})
+            ones = jnp.ones((slots,), jnp.int32)
+            lane_pages = {"full": ones, "ring": ones}
+        live = jnp.zeros((slots,), bool)
+        for active in buckets:
+            logits, cache = self._decode_paged(
+                self.params, cache, zeros, zeros, tables, live=live,
+                active_pages=active, lane_pages=lane_pages)
+            self._readback(logits, keys)
+        pos_leaves = {k: v for k, v in cache.items() if k.endswith("/pos")}
+        if pos_leaves:
+            n_full, n_ring = self._table_pages()
+            jax.block_until_ready(self._scrub(pos_leaves, jnp.full(
+                (max(n_full + n_ring, 1),), paged.GARBAGE_PAGE, jnp.int32)))
+
     def pool_pages(self, slots: int) -> int:
         """Pages of the pool :meth:`serve` builds for ``slots`` lanes:
         ``num_pages`` when given, else every lane's worst case.  Under a
@@ -2051,9 +2222,7 @@ class Engine:
         active = None
         lane_pages = None
         if self.kernel == "fused":
-            n_full, n_ring = self._table_pages()
-            active = (_bucket_pages(n_full, n_full),
-                      _bucket_pages(n_ring, n_ring))
+            active = self._active_pages(self.max_len)
             lane_pages = {"full": i32((slots,)), "ring": i32((slots,))}
         return self._decode_paged.lower(
             self.params, cache, toks, pos, tables, live=live,

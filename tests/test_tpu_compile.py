@@ -121,7 +121,10 @@ def test_gqa_decode_compiles(one_chip, mode):
                 q, kq, kd, vq, vd, pp, bt, pos, mode=mode,
                 active_pages=N_LOGICAL, impl="pallas", interpret=False)
         args = (q, *k, *v, pos_pool, bt, pos)
-    assert "tpu_custom_call" in _compile_text(fn, *args)
+    text = _compile_text(fn, *args)
+    assert "tpu_custom_call" in text
+    storage = {None: "float32", "bf16": "bfloat16"}.get(mode, mode)
+    assert f"%paged_attn_decode_full_{storage}" in text
 
 
 def _mla_dims():
@@ -151,7 +154,10 @@ def test_mla_decode_compiles(one_chip, modes):
                 active_pages=N_LOGICAL, impl="pallas", interpret=False)
         args = (q_eff, q_rope, *_kv_leaves(one_chip, (r,), modes[0]),
                 *_kv_leaves(one_chip, (dr,), modes[1]), bt, pos)
-    assert "tpu_custom_call" in _compile_text(fn, *args)
+    text = _compile_text(fn, *args)
+    assert "tpu_custom_call" in text
+    storage = "_".join(modes) if modes else "float32"
+    assert f"%paged_mla_decode_{storage}" in text
 
 
 @pytest.mark.parametrize("chunk", [CHUNK, MAX_LEN])
@@ -172,8 +178,9 @@ def test_gqa_prefill_compiles(one_chip, mode, chunk):
         return pa.paged_attn_prefill_quant(
             q, kq, kd, vq, vd, pp, bt, qpos, mode=mode,
             active_pages=N_LOGICAL, impl="pallas", interpret=False)
-    assert "tpu_custom_call" in _compile_text(fn, q, *k, *v, pos_pool, bt,
-                                              qpos)
+    text = _compile_text(fn, q, *k, *v, pos_pool, bt, qpos)
+    assert "tpu_custom_call" in text
+    assert f"%paged_attn_prefill_full_{mode}" in text
 
 
 @pytest.mark.parametrize("modes", [("q8_0", "q8_0"), ("q8_0", "q4_0")])
@@ -189,9 +196,11 @@ def test_mla_prefill_compiles(one_chip, modes):
             qe, qr, cq, cd, kq, kd, bt, qpos, scale=0.1,
             latent_mode=modes[0], rope_mode=modes[1],
             active_pages=N_LOGICAL, impl="pallas", interpret=False)
-    assert "tpu_custom_call" in _compile_text(
+    text = _compile_text(
         fn, q_eff, q_rope, *_kv_leaves(one_chip, (r,), modes[0]),
         *_kv_leaves(one_chip, (dr,), modes[1]), bt, qpos)
+    assert "tpu_custom_call" in text
+    assert f"%paged_mla_prefill_{'_'.join(modes)}" in text
 
 
 @pytest.mark.parametrize("step", ["decode", "prefill"])
