@@ -1,15 +1,21 @@
 """Fused Pallas paged-attention decode kernels (flash-decode over pages).
 
 One decode step attends a single query row per slot against that slot's KV
-pages **in place**: the grid runs over ``(slot, logical_page)`` with the
-page dimension innermost, the slot's block table rides in as a
-scalar-prefetch operand so each grid step DMAs exactly one physical page
-(``BlockSpec`` index map ``block_table[slot, page]``), and a running
-(max, sum-exp, accumulator) online softmax folds the page tiles together —
-no ``(B, max_len, ...)`` dense view is ever materialised.  Unallocated
-logical pages all map to the NULL page, so consecutive trailing grid steps
-revisit one resident block instead of streaming fresh memory: decode
-bandwidth scales with *live* pages, not ``slots x max_len``.
+pages **in place**: the slot's block table rides in as a scalar-prefetch
+operand, and a running (max, sum-exp, accumulator) online softmax folds the
+pages together — no ``(B, max_len, ...)`` dense view is ever materialised.
+Decode bandwidth scales with *live* pages, not ``slots x max_len``:
+
+  * GQA decode (:func:`_attn_core`) runs over ``(slot, block)``.  A block
+    is ``ppb = min(nj, 256 // page_size)`` pages (about ``_BLOCK_TOKENS``
+    tokens), each copied by its own DMA into a double-buffered VMEM block,
+    so one grid step does one MXU product over some 256 tokens instead of
+    one page.  A lane's blocks past its live pages are neither copied nor
+    computed, and each step prefetches the next live block in grid order.
+  * MLA decode and chunked prefill run over ``(slot, logical_page)``, one
+    physical page per step (``BlockSpec`` index map ``block_table[slot,
+    page]``); unallocated logical pages all map to the NULL page, so
+    consecutive trailing grid steps revisit one resident block.
 
 Two kernel scaffolds — GQA (:func:`_attn_core`) and absorbed MLA
 (:func:`_mla_core`) — are each parameterized over a K/V *tile loader*
@@ -83,11 +89,13 @@ pools; heads are independent, so there are no collectives), fully
 replicated otherwise — while the XLA twin stays a plain jit body and
 lets GSPMD partition the bounded gather over sharded pool operands.
 
-For full MXU/VPU utilisation on TPU, ``page_size`` should be a multiple of
-128 and head counts multiples of 8; the tests intentionally use tiny odd
-pages, which interpret mode accepts.  Under ``shard_map`` the 128-lane
-alignment contract applies to the *per-shard* shapes (global dim /
-mesh-axis size), which is what the pallas-contract lint rule checks.
+For full MXU/VPU utilisation on TPU, the one-page-per-step kernels (MLA,
+prefill) want ``page_size`` a multiple of 128 and head counts multiples of
+8; GQA decode's blocks reach 256 tokens from any page size.  The tests
+intentionally use tiny odd pages, which interpret mode accepts.  Under
+``shard_map`` the 128-lane alignment contract applies to the *per-shard*
+shapes (global dim / mesh-axis size), which is what the pallas-contract
+lint rule checks.
 """
 
 from __future__ import annotations
@@ -97,6 +105,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -136,6 +145,78 @@ def _lane_bound(lane_pages: jax.Array | None, b: int, nj: int) -> jax.Array:
     if lane_pages is None:
         return jnp.full((b,), nj, jnp.int32)
     return jnp.clip(lane_pages.astype(jnp.int32), 1, nj)
+
+
+# The GQA decode kernel attends a lane's pages a block at a time: about
+# _BLOCK_TOKENS tokens per grid step, enough keys that one step's copies and
+# products outweigh its fixed cost.
+
+_BLOCK_TOKENS = 256
+
+
+def _pages_per_block(page_size: int, nj: int) -> int:
+    """Pages per block: about ``_BLOCK_TOKENS`` tokens, at most the
+    bucket's ``nj``."""
+    return min(nj, max(1, _BLOCK_TOKENS // page_size))
+
+
+def _block_pages(live, blk, ppb):
+    """The fetch plan: how many pages of block ``blk`` a lane with ``live``
+    live pages copies — its live pages in that block, from the block's
+    first on; 0 for a block past them.  Python ints or traced scalars."""
+    return jnp.minimum(jnp.maximum(live - blk * ppb, 0), ppb)
+
+
+def _next_block(lane, blk, live, ppb):
+    """Grid position ``(lane, blk)`` of the live block after ``(lane,
+    blk)``: the lane's next block while it has live pages, else the next
+    lane's first (every lane has a live page)."""
+    more = (blk + 1) * ppb < live
+    return jnp.where(more, lane, lane + 1), jnp.where(more, blk + 1, 0)
+
+
+def _page_rows(x: jax.Array, paired: bool) -> jax.Array:
+    """(num_pages, P, Hkv, W) K/V leaf -> (num_pages, rows, W') view the
+    kernel copies a page at a time: one row per (token, kv head) in storage
+    order, so the view of a row-major pool costs nothing; ``paired`` puts
+    two consecutive rows side by side."""
+    n, tp, hkv, w = x.shape
+    if paired:
+        return x.reshape(n, tp * hkv // 2, 2 * w)
+    return x.reshape(n, tp * hkv, w)
+
+
+def _column_order(n: int, paired: bool) -> np.ndarray:
+    """Storage index (token-major, kv head minor) of each of a block's
+    ``n`` key rows in the kernel's column order: paired q4_0 rows unpack
+    to every even row, then every odd one."""
+    idx = np.arange(n)
+    return np.concatenate([idx[0::2], idx[1::2]]) if paired else idx
+
+
+def _block_columns(x: jax.Array, b: int, nb: int, cols: int,
+                   paired: bool) -> jax.Array:
+    """Per-key side data gathered through the block table, (B, nb * ppb,
+    P, Hkv) -> (B, 1, nb * cols), each block's columns in kernel order."""
+    x = x.reshape(b, nb, cols)
+    if paired:
+        x = x[..., _column_order(cols, paired)]
+    return x.reshape(b, 1, nb * cols)
+
+
+def _block_values(raw: jax.Array, quant, paired: bool) -> jax.Array:
+    """(ppb, rows, W') copied pages -> (cols, D) key or value rows in
+    column order.  Quantized rows come back as f32 integers: their row
+    scales multiply the score and probability columns instead."""
+    v = raw.reshape(-1, raw.shape[-1])
+    if quant is None:
+        return v
+    v = raw.astype(jnp.int32).reshape(v.shape)
+    if quant == "q4_0":
+        w = v.shape[-1] // 2
+        v = (jnp.concatenate([_q4_values(v[:, :w]), _q4_values(v[:, w:])])
+             if paired else _q4_values(v))
+    return v.astype(jnp.float32)
 
 
 # Pallas TPU blocks must match the (8, 128) tiling in their last two dims
@@ -246,18 +327,18 @@ def paged_attn_decode(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     position ``t`` is attendable iff ``0 <= t <= pos`` and, when
     ``window > 0``, ``t > pos - window``.  ``lane_pages`` (B,) int32
     optionally bounds each lane's page loop to its *own* live page count
-    (grid steps past it revisit the lane's last resident page — no fresh
-    DMA, so a short lane's reads no longer scale with the batch-max
-    bound).  Every live key must sit inside the first ``lane_pages[i]``
-    logical pages.  Returns (B, H, Dv) f32.
+    (pages past it are never copied, so a short lane's reads do not scale
+    with the batch-max bound).  Every live key must sit inside the first
+    ``lane_pages[i]`` logical pages.  Returns (B, H, Dv) f32.
     """
+    nj = _n_active(block_table, active_pages)
     return _attn_core(
         q, (k_pool, v_pool), pos_pool, block_table, pos,
-        _lane_bound(lane_pages, q.shape[0],
-                    _n_active(block_table, active_pages)),
+        _lane_bound(lane_pages, q.shape[0], nj),
         window=window, softcap=softcap,
         scale=(q.shape[-1] ** -0.5 if scale is None else scale),
-        nj=_n_active(block_table, active_pages), impl=_resolve_impl(impl),
+        nj=nj, ppb=_pages_per_block(k_pool.shape[1], nj),
+        impl=_resolve_impl(impl),
         interpret=(_interpret_default() if interpret is None else interpret),
         quant=None, mesh=mesh)
 
@@ -318,24 +399,34 @@ def _xla_attn(q, ks, vs, ps, pos, *, window, softcap, scale):
 
 
 @partial(jax.jit, static_argnames=("window", "softcap", "scale", "nj",
-                                   "impl", "interpret", "quant", "mesh"))
+                                   "ppb", "impl", "interpret", "quant",
+                                   "mesh"))
 def _attn_core(q, kv, pos_pool, block_table, pos, lane_pages, *,
                window: int, softcap: float, scale: float, nj: int,
-               impl: str, interpret: bool, quant: str | None,
+               ppb: int, impl: str, interpret: bool, quant: str | None,
                mesh=None) -> jax.Array:
     """Shared GQA flash-decode scaffold.  ``kv`` is ``(k_pool, v_pool)``
     (``quant=None``) or ``(k_qs, k_d, v_qs, v_d)`` with ``quant`` naming
     the storage mode ("q8_0" | "q4_0" — q4 leaves are nibble-packed, so
     their trailing axis is half the head dim); the
-    score/mask/online-softmax body is identical — only the page tile
-    loader changes (f32 load vs int8 * per-row scale on the VPU, with an
-    arithmetic-shift nibble unpack first for q4_0).
+    score/mask/online-softmax body is identical — only the block loader
+    changes (pages as stored, or int8 values with an arithmetic-shift
+    nibble unpack first for q4_0, whose row scales multiply the score and
+    probability columns).
 
-    ``lane_pages`` (B,) int32 in ``[1, nj]`` further bounds each lane:
-    index maps clamp the page lookup to ``min(j, lane_pages[i] - 1)`` so
-    trailing grid steps revisit the lane's own last page (already
-    resident — Pallas skips the copy), and the validity mask gains
-    ``j < lane_pages[i]`` so the revisited page is never double-counted.
+    The grid is ``(slot, block)``, a block being ``ppb`` logical pages.
+    Each page of a block is one DMA of its ``(P * Hkv, D)`` rows (token
+    major, kv head minor: the pool's own order) into a double-buffered
+    VMEM block, so one product scores every query head against the
+    block's ``ppb * P * Hkv`` key rows; a constant head mask keeps each
+    query head to its group's columns.  ``lane_pages`` (B,) int32 in
+    ``[1, nj]`` bounds each lane: blocks past its live pages are neither
+    fetched nor computed, and in its last live block only its live pages
+    are fetched (:func:`_block_pages`).  Each live step starts the copies
+    of the next live block in grid order (:func:`_next_block`), the next
+    lane's first included.  Positions (and quantized scales) are gathered
+    through the block table beforehand, lane-major, with ``-1`` past the
+    lane's live pages, like the XLA twin's.
 
     ``mesh`` (static): run the Pallas path under ``shard_map`` on it —
     head-parallel when the kv-head axis divides the ``model`` axis, fully
@@ -351,8 +442,8 @@ def _attn_core(q, kv, pos_pool, block_table, pos, lane_pages, *,
         btj = block_table[:, :nj]
         ks, vs = _gathered_kv(kv, btj, quant)
         ps = pos_pool[btj]                                   # (B, nj, P)
-        # out-of-lane pages read as unwritten (pos = -1), mirroring the
-        # fused kernel's j < lane_pages[i] mask
+        # out-of-lane pages read as unwritten (pos = -1), as in the
+        # fused kernel
         ps = jnp.where(jnp.arange(nj)[None, :, None] < lane_pages[:, None,
                                                                   None],
                        ps, -1)
@@ -364,7 +455,7 @@ def _attn_core(q, kv, pos_pool, block_table, pos, lane_pages, *,
     def shard_run(block_table, pos, lane_pages, q, *rest):
         """Build + invoke the pallas_call.  Shapes derive from the
         operands, which are *per-shard* inside shard_map — so the kernel,
-        BlockSpecs and scratch all see the local head slice."""
+        the page views and scratch all see the local head slice."""
         *kv_ops, pos_pool = rest
         b, h, d = q.shape
         tp, hkv = kv_ops[0].shape[1], kv_ops[0].shape[2]
@@ -372,77 +463,129 @@ def _attn_core(q, kv, pos_pool, block_table, pos, lane_pages, *,
         if quant == "q4_0":
             dv *= 2
         rep = h // hkv
+        nb = -(-nj // ppb)
+        cols = ppb * tp * hkv           # key rows of one block, all heads
+        # a packed q4_0 row is half a head wide, and the chip copies whole
+        # 128-lane rows: copy its pages two key rows to a row
+        paired = quant == "q4_0" and (tp * hkv) % 2 == 0
+        vals = (kv_ops[0], kv_ops[2]) if quant else tuple(kv_ops)
+        k_rows, v_rows = (_page_rows(x, paired) for x in vals)
 
-        def kernel(bt_ref, pos_ref, lp_ref, q_ref, *refs):
-            del bt_ref
-            *kv_refs, pp_ref, o_ref, m_ref, l_ref, acc_ref = refs
-            _init_accumulators(m_ref, l_ref, acc_ref)
-            if quant:
-                kq_ref, kd_ref, vq_ref, vd_ref = kv_refs
-                kt = _dequant(kq_ref[0], kd_ref[0], quant)
-
-                def v_pages():
-                    return _dequant(vq_ref[0], vd_ref[0], quant)
-            else:
-                k_ref, v_ref = kv_refs
-                kt = k_ref[0].astype(jnp.float32)            # (P, Hkv, D)
-
-                def v_pages():
-                    return v_ref[0].astype(jnp.float32)
-
-            qv = q_ref[0].astype(jnp.float32) * scale        # (H, D)
-            q2 = qv.reshape(hkv, rep, d)
-            s = jax.lax.dot_general(                         # (Hkv, rep, P)
-                q2, kt, (((2,), (2,)), ((0,), (1,))),
-                preferred_element_type=jnp.float32).reshape(h, tp)
-            if softcap:
-                s = softcap * jnp.tanh(s / softcap)
-            pt = pp_ref[0]                                   # (1, P) int32
-            pb = pos_ref[pl.program_id(0)]
-            valid = (pt >= 0) & (pt <= pb)
-            if window:
-                valid &= pt > pb - window
-            # clamped trailing steps revisit the lane's last (live!) page:
-            # mask them out so its keys are not folded in twice
-            valid &= pl.program_id(1) < lp_ref[pl.program_id(0)]
-            s = jnp.where(valid, s, NEG_INF)
-
-            def v_tile(p):
-                p3 = p.reshape(hkv, rep, tp)
-                return jax.lax.dot_general(                  # (Hkv, rep, Dv)
-                    p3, v_pages(), (((2,), (0,)), ((0,), (1,))),
-                    preferred_element_type=jnp.float32).reshape(h, dv)
-
-            _online_update(s, valid, v_tile, m_ref, l_ref, acc_ref)
-            _finish(o_ref, acc_ref, l_ref, nj)
-
-        # clamp to the lane's last live page: consecutive trailing grid
-        # steps then resolve to the same physical block, which Pallas
-        # keeps resident instead of issuing a fresh DMA
-        pj = lambda i, j, bt, ps, lp: bt[i, jnp.minimum(j, lp[i] - 1)]  # noqa: E731,E501
-        page4 = lambda i, j, bt, ps, lp: (pj(i, j, bt, ps, lp), 0, 0, 0)  # noqa: E731,E501
-        page3 = lambda i, j, bt, ps, lp: (pj(i, j, bt, ps, lp), 0, 0)     # noqa: E731,E501
+        # per-key side data, lane-major in the kernel's column order:
+        # stored positions and, for quantized pools, the K and V row
+        # scales; past each lane's live pages they read -1 and 0, so the
+        # pages the kernel never copies weigh nothing
+        btp = jnp.pad(block_table[:, :nj], ((0, 0), (0, nb * ppb - nj)))
+        live = (jnp.arange(nb * ppb)[None, :]
+                < lane_pages[:, None])[:, :, None, None]
+        side = [jnp.where(live, pos_pool[btp][..., None], -1)]
         if quant:
-            # spec shapes follow the *stored* leaves (packed trailing
-            # axis for q4_0) — the kernel unpacks after the DMA
-            kv_specs = [
-                pl.BlockSpec((1, tp, hkv, kv_ops[0].shape[-1]), page4),
-                pl.BlockSpec((1, tp, hkv), page3),
-                pl.BlockSpec((1, tp, hkv, kv_ops[2].shape[-1]), page4),
-                pl.BlockSpec((1, tp, hkv), page3),
-            ]
-        else:
-            kv_specs = [
-                pl.BlockSpec((1, tp, hkv, d), page4),
-                pl.BlockSpec((1, tp, hkv, dv), page4),
-            ]
+            side += [jnp.where(live, kv_ops[i][btp], 0.0) for i in (1, 3)]
+        side = [_block_columns(jnp.broadcast_to(x, (b, nb * ppb, tp, hkv)),
+                               b, nb, cols, paired) for x in side]
+        # column c holds a key of kv head _column_order(...)[c] % hkv:
+        # each query head attends its own group's columns
+        head_mask = jnp.asarray(
+            _column_order(cols, paired)[None, :] % hkv
+            == np.arange(h)[:, None] // rep, jnp.int32)
+
+        def fetch(refs, lane, blk, slot, start):
+            """Start (or wait for) the DMAs of one block: the lane's live
+            pages in it, one copy per page and leaf (the fetch plan of
+            :func:`_block_pages`)."""
+            bt_ref, lp_ref, srcs, bufs, sems = refs
+
+            def page(pg, carry):
+                src = bt_ref[lane, blk * ppb + pg] if start else 0
+                for x, buf, sem in zip(srcs, bufs, sems):
+                    cp = pltpu.make_async_copy(x.at[src], buf.at[slot, pg],
+                                               sem.at[slot])
+                    if start:
+                        cp.start()
+                    else:
+                        cp.wait()
+                return carry
+            jax.lax.fori_loop(
+                0, _block_pages(lp_ref[lane], blk, ppb), page, 0)
+
+        def kernel(bt_ref, pos_ref, lp_ref, q_ref, mask_ref, *refs):
+            (*side_refs, k_hbm, v_hbm, o_ref, m_ref, l_ref, acc_ref,
+             kbuf, vbuf, ksem, vsem, slot_ref) = refs
+            i, blk = pl.program_id(0), pl.program_id(1)
+            dma = (bt_ref, lp_ref, (k_hbm, v_hbm), (kbuf, vbuf),
+                   (ksem, vsem))
+
+            @pl.when((i == 0) & (blk == 0))
+            def _():
+                # pages past a lane's live count are never fetched: their
+                # buffer slots keep an earlier block's (finite) keys or
+                # these zeros, and their columns are masked
+                kbuf[...] = jnp.zeros_like(kbuf)
+                vbuf[...] = jnp.zeros_like(vbuf)
+                slot_ref[0] = 0
+                fetch(dma, 0, 0, 0, True)
+
+            _init_accumulators(m_ref, l_ref, acc_ref)
+
+            @pl.when(_block_pages(lp_ref[i], blk, ppb) > 0)
+            def _():
+                slot = slot_ref[0]
+                nxt_i, nxt_blk = _next_block(i, blk, lp_ref[i], ppb)
+
+                @pl.when(nxt_i < b)
+                def _():
+                    fetch(dma, nxt_i, nxt_blk, 1 - slot, True)
+
+                slot_ref[0] = 1 - slot
+                fetch(dma, i, blk, slot, False)
+
+                qv = q_ref[0]                                # (H, D)
+                kt = _block_values(kbuf[slot], quant, paired)
+                kt = kt.astype(jnp.promote_types(kt.dtype, qv.dtype))
+                s = jax.lax.dot_general(                     # (H, cols)
+                    qv.astype(kt.dtype), kt, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                if quant:
+                    pt_ref, kd_ref, vd_ref = side_refs
+                    s = s * kd_ref[0]
+                else:
+                    (pt_ref,) = side_refs
+                s = s * scale
+                if softcap:
+                    s = softcap * jnp.tanh(s / softcap)
+                pt = pt_ref[0]                               # (1, cols)
+                pb = pos_ref[i]
+                valid = (mask_ref[...] != 0) & (pt >= 0) & (pt <= pb)
+                if window:
+                    valid &= pt > pb - window
+                s = jnp.where(valid, s, NEG_INF)
+
+                def v_tile(p):
+                    if quant:
+                        p = p * vd_ref[0]
+                    vt = _block_values(vbuf[slot], quant, paired)
+                    return jax.lax.dot_general(              # (H, Dv)
+                        p, vt.astype(jnp.float32), (((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32)
+
+                _online_update(s, valid, v_tile, m_ref, l_ref, acc_ref)
+
+            _finish(o_ref, acc_ref, l_ref, nb)
+
+        # a dead block's side data resolves to the lane's last live block,
+        # which Pallas keeps resident instead of copying again
+        def side_map(i, blk, bt, ps, lp):
+            return i, 0, jnp.minimum(blk, (lp[i] - 1) // ppb)
+
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=(b, nj),
+            grid=(b, nb),
             in_specs=[
                 pl.BlockSpec((1, h, d), lambda i, j, bt, ps, lp: (i, 0, 0)),
-                *kv_specs,
-                pl.BlockSpec((1, 1, tp), page3),
+                pl.BlockSpec((h, cols), lambda i, j, bt, ps, lp: (0, 0)),
+                *[pl.BlockSpec((1, 1, cols), side_map) for _ in side],
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
             ],
             out_specs=pl.BlockSpec((1, h, dv),
                                    lambda i, j, bt, ps, lp: (i, 0, 0)),
@@ -450,16 +593,26 @@ def _attn_core(q, kv, pos_pool, block_table, pos, lane_pages, *,
                 pltpu.VMEM((h, _LANES), jnp.float32),
                 pltpu.VMEM((h, _LANES), jnp.float32),
                 pltpu.VMEM((h, dv), jnp.float32),
+                pltpu.VMEM((2, ppb, *k_rows.shape[1:]), k_rows.dtype),
+                pltpu.VMEM((2, ppb, *v_rows.shape[1:]), v_rows.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SMEM((1,), jnp.int32),
             ],
         )
         return pl.pallas_call(
             kernel,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((b, h, dv), jnp.float32),
+            # each step prefetches the next live block, so the grid runs
+            # in order
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary", "arbitrary")),
             interpret=interpret,
             name=_kernel_name("attn_decode", "ring" if window else "full",
                               quant or kv_ops[0].dtype),
-        )(block_table, pos, lane_pages, q, *kv_ops, _row_leaf(pos_pool))
+        )(block_table[:, :nj], pos, lane_pages, q, head_mask, *side,
+          k_rows, v_rows)
 
     args = (block_table, pos, lane_pages, q, *kv, pos_pool)
     if mesh is None:
@@ -809,13 +962,14 @@ def paged_attn_decode_quant(q: jax.Array, k_qs: jax.Array, k_d: jax.Array,
     HBM traffic per page is ~1/4 (q8_0) / ~1/7 (q4_0) of the f32 pools'.
     Numerically exact w.r.t. attending the dequantised pools.
     """
+    nj = _n_active(block_table, active_pages)
     return _attn_core(
         q, (k_qs, k_d, v_qs, v_d), pos_pool, block_table, pos,
-        _lane_bound(lane_pages, q.shape[0],
-                    _n_active(block_table, active_pages)),
+        _lane_bound(lane_pages, q.shape[0], nj),
         window=window, softcap=softcap,
         scale=(q.shape[-1] ** -0.5 if scale is None else scale),
-        nj=_n_active(block_table, active_pages), impl=_resolve_impl(impl),
+        nj=nj, ppb=_pages_per_block(k_qs.shape[1], nj),
+        impl=_resolve_impl(impl),
         interpret=(_interpret_default() if interpret is None else interpret),
         quant=_check_mode(mode), mesh=mesh)
 

@@ -212,46 +212,158 @@ def test_mla_lane_pages_bound(impl):
     assert np.max(np.abs(full - bounded)) < TOL
 
 
-def test_lane_pages_dma_count_proxy():
-    """A short lane's page fetches must not scale with the longest lane
-    in the batch.  The kernels clamp the block-table index map to
-    ``bt[i, min(j, lane_pages[i]-1)]``; Pallas skips the DMA whenever
-    consecutive grid steps resolve to the same physical page, so the
-    number of DISTINCT fetches per lane is the lane's own page count.
-    This replays the exact index-map arithmetic as the regression
-    oracle."""
-    page_size, n_lp = 4, 8
-    pos = np.array([2, 30], np.int32)
-    lane_pages = [paged.pages_for(int(p) + 1, page_size) for p in pos]
-    assert lane_pages == [1, 8]
-    bt = np.full((2, n_lp), paged.NULL_PAGE, np.int32)
-    nxt = paged.RESERVED_PAGES
-    for i in range(2):
-        for lp_ in range(lane_pages[i]):
-            bt[i, lp_] = nxt
-            nxt += 1
-    fetches = []
-    for i, lp_i in enumerate(lane_pages):
-        seen, last = [], None
-        for j in range(n_lp):          # batch-max bucket drives the grid
-            pj = bt[i, min(j, lp_i - 1)]
-            if pj != last:             # unchanged index -> no new DMA
-                seen.append(pj)
-            last = pj
-        fetches.append(len(seen))
-    # the short lane fetches exactly its 1 page even though the grid ran
-    # 8 steps for its 30-token neighbor
-    assert fetches == lane_pages
-    # without the clamp the short lane also fetches the NULL tail —
-    # strictly more DMAs, and page-sized ones
-    unclamped = []
-    last = None
-    for j in range(n_lp):
-        pj = bt[0, j]
-        if pj != last:
-            unclamped.append(pj)
-        last = pj
-    assert len(unclamped) > lane_pages[0]
+def test_block_fetch_plan():
+    """Replays the GQA decode kernel's fetch plan over its (slot, block)
+    grid with the helpers the kernel runs (``_block_pages``,
+    ``_next_block``): every lane copies exactly its own live pages, each
+    once and in order, and none of its NULL tail; a block is copied only
+    if it is live, and each live block's copies are started by the live
+    step before it in grid order (the next lane's first block included),
+    so only the call's first block is started on its own step."""
+    plans = ((4, [1, 3, 4, 5, 12, 9]), (1, [1, 2, 3]),
+             (16, [1, 15, 16, 17, 40]), (8, [8, 8]))
+    for ppb, lane_pages in plans:
+        b, nj = len(lane_pages), max(lane_pages)
+        nb = -(-nj // ppb)
+        bt = np.full((b, nj), paged.NULL_PAGE, np.int32)
+        nxt = paged.RESERVED_PAGES
+        for i, n in enumerate(lane_pages):
+            bt[i, :n] = np.arange(nxt, nxt + n)
+            nxt += n
+        copied = [[] for _ in range(b)]
+
+        def start(lane, blk):
+            n = int(paged_attn._block_pages(lane_pages[lane], blk, ppb))
+            assert n > 0, (lane, blk)              # only live blocks
+            copied[lane] += [int(bt[lane, blk * ppb + pg])
+                             for pg in range(n)]
+            return lane, blk
+
+        started, live_steps = [start(0, 0)], []
+        for i in range(b):
+            for blk in range(nb):
+                if int(paged_attn._block_pages(lane_pages[i], blk,
+                                               ppb)) == 0:
+                    continue                        # dead step: nothing
+                assert started[-1] == (i, blk)      # prefetched earlier
+                live_steps.append((i, blk))
+                ni, nblk = (int(x) for x in paged_attn._next_block(
+                    i, blk, lane_pages[i], ppb))
+                if ni < b:
+                    started.append(start(ni, nblk))
+        assert started == live_steps
+        assert len(live_steps) == sum(-(-n // ppb) for n in lane_pages)
+        for i, n in enumerate(lane_pages):
+            assert copied[i] == list(bt[i, :n]), (ppb, i)
+            assert paged.NULL_PAGE not in copied[i]
+
+
+def _lane_pools(rng, pos, page_size, n_lp, hkv, d, dv, ring):
+    """Pools for lanes at positions ``pos``, each lane owning ``n_lp``
+    physical pages.  Full tables store position = logical index; a
+    ``ring`` table of ``n_lp`` pages holds at slot s the latest position
+    t <= pos with t = s mod its length.  Returns the pools, the block
+    table (NULL past each lane's live pages), the lane page counts, and
+    a poisoned table whose entries past them point at the lane's other
+    pages, filled with NaN keys and values and in-range positions."""
+    b, span = len(pos), n_lp * page_size
+    n_pages = paged.RESERVED_PAGES + b * n_lp
+    k_pool = rng.normal(size=(n_pages, page_size, hkv, d)).astype(np.float32)
+    v_pool = rng.normal(size=(n_pages, page_size, hkv, dv)).astype(np.float32)
+    pos_pool = np.full((n_pages, page_size), -1, np.int32)
+    k_pool[paged.NULL_PAGE] = v_pool[paged.NULL_PAGE] = 0.0
+    bt = np.full((b, n_lp), paged.NULL_PAGE, np.int32)
+    poisoned = np.zeros_like(bt)
+    lane_pages = []
+    for i, p in enumerate(pos):
+        n = paged.pages_for((min(p + 1, span) if ring else p + 1), page_size)
+        lane_pages.append(n)
+        own = paged.RESERVED_PAGES + i * n_lp + np.arange(n_lp)
+        poisoned[i], bt[i, :n] = own, own[:n]
+        for s in range(n * page_size):
+            t = p - (p - s) % span if ring else s
+            if 0 <= t <= p:
+                pos_pool[own[s // page_size], s % page_size] = t
+        k_pool[own[n:]] = v_pool[own[n:]] = np.nan
+        pos_pool[own[n:]] = 0
+    return (k_pool, v_pool, pos_pool, bt, np.asarray(lane_pages, np.int32),
+            poisoned)
+
+
+# (page_size, nj, lane positions, window, softcap, storage, lane bound
+# given, block tokens): with 16 block tokens a block is 4 pages of 4
+BLOCK_CASES = {
+    # lane pages 1, ppb - 1, ppb, ppb + 1 and the whole bucket
+    "edges": (4, 12, [2, 9, 15, 17, 47], 0, 0.0, None, True, 16),
+    # no lane bound: every lane walks the bucket, NULL tails included
+    "null_tails": (4, 12, [2, 17, 47], 0, 0.0, None, False, 16),
+    # a bucket smaller than a block: one block of the bucket's pages
+    "small_bucket": (4, 2, [0, 5, 7], 0, 0.0, None, True, 16),
+    # wrapped ring tables with a window, and softcap
+    "ring": (4, 6, [2, 13, 30, 47], 7, 20.0, None, True, 16),
+    "q8_0": (4, 12, [0, 13, 16, 47], 0, 0.0, "q8_0", True, 16),
+    "q4_0": (4, 12, [0, 13, 16, 47], 9, 15.0, "q4_0", True, 16),
+    # the serving pools' dtype, bf16 queries and pages
+    "bf16": (4, 12, [3, 16, 33], 0, 0.0, "bf16", True, 16),
+    # the module's own block size: 64 pages of 4
+    "block_256": (4, 72, [0, 251, 255, 259, 287], 0, 0.0, None, True,
+                  None),
+}
+
+
+@pytest.mark.parametrize("case", list(BLOCK_CASES))
+def test_block_loop_matches_oracle(case, monkeypatch):
+    """The GQA decode kernel's block loop (Pallas, interpret mode) and its
+    XLA twin against the dense oracle, over lanes that end in every
+    position of a block.  The kernel runs on the poisoned table: a copy
+    of any page past a lane's live pages would put NaN in its output."""
+    (page_size, nj, pos, window, softcap, storage, bounded,
+     block_tokens) = BLOCK_CASES[case]
+    if block_tokens:
+        monkeypatch.setattr(paged_attn, "_BLOCK_TOKENS", block_tokens)
+    rng = np.random.default_rng(list(BLOCK_CASES).index(case))
+    h, hkv, d = 4, 2, 16
+    dv = 8 if storage is None else d
+    pos = np.asarray(pos, np.int32)
+    k_pool, v_pool, pos_pool, bt, lane_pages, poisoned = _lane_pools(
+        rng, pos, page_size, nj, hkv, d, dv, ring=bool(window))
+    q = rng.normal(size=(len(pos), h, d)).astype(np.float32)
+    kw = dict(window=window, softcap=softcap,
+              lane_pages=jnp.asarray(lane_pages) if bounded else None,
+              active_pages=nj)
+    if storage in ("q8_0", "q4_0"):
+        # int8 values cannot hold NaN: poison the pages' row scales
+        extra = np.setdiff1d(poisoned, bt)
+        leaves = []
+        for x in (k_pool, v_pool):
+            xq, xd = paged.quantize_rows(jnp.asarray(np.nan_to_num(x)),
+                                         storage)
+            leaves.append((xq, xd.at[extra].set(jnp.nan)))
+        (kq, kd), (vq, vd) = leaves
+        k_pool, v_pool = (np.asarray(paged_attn._dequant(x, s, storage))
+                          for x, s in leaves)
+
+        def attend(table, impl):
+            return paged_attn.paged_attn_decode_quant(
+                jnp.asarray(q), kq, kd, vq, vd, jnp.asarray(pos_pool),
+                jnp.asarray(table), jnp.asarray(pos), mode=storage,
+                impl=impl, **kw)
+    else:
+        dt = jnp.bfloat16 if storage == "bf16" else jnp.float32
+        q, k_pool, v_pool = (np.asarray(jnp.asarray(x, dt), np.float32)
+                             for x in (q, k_pool, v_pool))
+
+        def attend(table, impl):
+            return paged_attn.paged_attn_decode(
+                jnp.asarray(q, dt), jnp.asarray(k_pool, dt),
+                jnp.asarray(v_pool, dt), jnp.asarray(pos_pool),
+                jnp.asarray(table), jnp.asarray(pos), impl=impl, **kw)
+    ref = _dense_oracle(q, k_pool, v_pool, pos_pool, bt, pos, window,
+                        softcap)
+    twin = np.asarray(attend(bt, "xla"))
+    got = np.asarray(attend(poisoned if bounded else bt, "pallas"))
+    assert np.max(np.abs(twin - ref)) < TOL
+    assert np.max(np.abs(got - ref)) < TOL
 
 
 @pytest.mark.parametrize("impl", ["pallas", "xla"])

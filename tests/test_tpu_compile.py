@@ -36,6 +36,8 @@ MAX_LEN = 512
 CHUNK = 32
 N_LOGICAL = MAX_LEN // PAGE
 NUM_PAGES = paged.RESERVED_PAGES + SLOTS * N_LOGICAL
+# the benchmark's chat cell: 64 lanes, a live-horizon bucket of 64 pages
+CELL = (64, 64)
 
 
 @pytest.fixture(scope="module")
@@ -81,47 +83,63 @@ def _compile_text(fn, *specs) -> str:
     return jax.jit(fn).lower(*specs).compile().as_text()
 
 
-def _kv_leaves(sh, row_shape, mode):
+def _kv_leaves(sh, row_shape, mode, num_pages=NUM_PAGES):
     """Shapes of one quantized K/V-style leaf pair, or of one unquantized
     leaf (``mode`` None: f32, or "bf16" — the serving pools' model dtype)."""
     if mode in (None, "bf16"):
         dtype = jnp.bfloat16 if mode else jnp.float32
-        return (_spec(sh, (NUM_PAGES, PAGE, *row_shape), dtype),)
+        return (_spec(sh, (num_pages, PAGE, *row_shape), dtype),)
     width = row_shape[-1]
     if mode == "q4_0":
         width = paged.q4_packed_dim(width)
-    return (_spec(sh, (NUM_PAGES, PAGE, *row_shape[:-1], width), jnp.int8),
-            _spec(sh, (NUM_PAGES, PAGE, *row_shape[:-1]), jnp.float32))
+    return (_spec(sh, (num_pages, PAGE, *row_shape[:-1], width), jnp.int8),
+            _spec(sh, (num_pages, PAGE, *row_shape[:-1]), jnp.float32))
 
 
-def _tables(sh):
-    return (_spec(sh, (SLOTS, N_LOGICAL), jnp.int32),
-            _spec(sh, (SLOTS,), jnp.int32))
+def _tables(sh, slots=SLOTS, n_logical=N_LOGICAL):
+    return (_spec(sh, (slots, n_logical), jnp.int32),
+            _spec(sh, (slots,), jnp.int32))
 
 
-@pytest.mark.parametrize("mode", [None, "bf16", "q8_0", "q4_0"])
-def test_gqa_decode_compiles(one_chip, mode):
+@pytest.mark.parametrize("mode, geometry", [
+    pytest.param(None, None, id="None"),
+    pytest.param("bf16", None, id="bf16"),
+    pytest.param("q8_0", None, id="q8_0"),
+    pytest.param("q4_0", None, id="q4_0"),
+    pytest.param("bf16", CELL, id="bf16-cell"),
+    pytest.param("q8_0", CELL, id="q8_0-cell"),
+    pytest.param("q4_0", CELL, id="q4_0-cell"),
+])
+def test_gqa_decode_compiles(one_chip, mode, geometry):
+    """qwen2-1.5b's decode attention, with per-lane page bounds as the
+    engine passes them; ``geometry`` (lanes, bucket pages) the chat cell's,
+    where a block is 16 pages and a lane runs up to 4 of them."""
     cfg = get_config("qwen2-1.5b")
     h, hkv, d = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    q = _spec(one_chip, (SLOTS, h, d), jnp.float32)
-    k = _kv_leaves(one_chip, (hkv, d), mode)
-    v = _kv_leaves(one_chip, (hkv, d), mode)
-    pos_pool = _spec(one_chip, (NUM_PAGES, PAGE), jnp.int32)
-    bt, pos = _tables(one_chip)
+    slots, n_logical = geometry or (SLOTS, N_LOGICAL)
+    num_pages = paged.RESERVED_PAGES + slots * n_logical
+    # bf16 queries score bf16 pages as stored (the cell's activations);
+    # f32 queries promote them
+    q = _spec(one_chip, (slots, h, d),
+              jnp.bfloat16 if geometry and mode == "bf16" else jnp.float32)
+    k = _kv_leaves(one_chip, (hkv, d), mode, num_pages)
+    v = _kv_leaves(one_chip, (hkv, d), mode, num_pages)
+    pos_pool = _spec(one_chip, (num_pages, PAGE), jnp.int32)
+    bt, pos = _tables(one_chip, slots, n_logical)
+    lanes = pos
 
     if mode in (None, "bf16"):
-        def fn(q, k, v, pp, bt, pos):
+        def fn(q, k, v, pp, bt, pos, lanes):
             return pa.paged_attn_decode(
-                q, k, v, pp, bt, pos, active_pages=N_LOGICAL,
-                impl="pallas", interpret=False)
-        args = (q, *k, *v, pos_pool, bt, pos)
+                q, k, v, pp, bt, pos, active_pages=n_logical,
+                lane_pages=lanes, impl="pallas", interpret=False)
     else:
-        def fn(q, kq, kd, vq, vd, pp, bt, pos):
+        def fn(q, kq, kd, vq, vd, pp, bt, pos, lanes):
             return pa.paged_attn_decode_quant(
                 q, kq, kd, vq, vd, pp, bt, pos, mode=mode,
-                active_pages=N_LOGICAL, impl="pallas", interpret=False)
-        args = (q, *k, *v, pos_pool, bt, pos)
-    text = _compile_text(fn, *args)
+                active_pages=n_logical, lane_pages=lanes, impl="pallas",
+                interpret=False)
+    text = _compile_text(fn, q, *k, *v, pos_pool, bt, pos, lanes)
     assert "tpu_custom_call" in text
     storage = {None: "float32", "bf16": "bfloat16"}.get(mode, mode)
     assert f"%paged_attn_decode_full_{storage}" in text
@@ -203,29 +221,35 @@ def test_mla_prefill_compiles(one_chip, modes):
     assert f"%paged_mla_prefill_{'_'.join(modes)}" in text
 
 
-@pytest.mark.parametrize("step", ["decode", "prefill"])
-def test_gqa_q8_compiles_on_mesh(mesh2x2, step):
+@pytest.mark.parametrize("step, geometry", [
+    pytest.param("decode", None, id="decode"),
+    pytest.param("prefill", None, id="prefill"),
+    pytest.param("decode", CELL, id="decode-cell"),
+])
+def test_gqa_q8_compiles_on_mesh(mesh2x2, step, geometry):
     """q8_0 pools with their kv-head axis on ``model`` (2 kv heads over a
     model axis of 2), queries and tables replicated, as Engine(mesh=2x2)
-    lays them out."""
+    lays them out; ``geometry`` as in :func:`test_gqa_decode_compiles`."""
     cfg = get_config("qwen2-1.5b")
     h, hkv, d = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    slots, n_logical = geometry or (SLOTS, N_LOGICAL)
+    num_pages = paged.RESERVED_PAGES + slots * n_logical
     PS = jax.sharding.PartitionSpec
     rep = jax.sharding.NamedSharding(mesh2x2, PS())
     head4 = jax.sharding.NamedSharding(mesh2x2, PS(None, None, "model", None))
     head3 = jax.sharding.NamedSharding(mesh2x2, PS(None, None, "model"))
-    kq, kd = (_spec(head4, (NUM_PAGES, PAGE, hkv, d), jnp.int8),
-              _spec(head3, (NUM_PAGES, PAGE, hkv), jnp.float32))
-    pos_pool = _spec(rep, (NUM_PAGES, PAGE), jnp.int32)
-    bt, pos = _tables(rep)
+    kq, kd = (_spec(head4, (num_pages, PAGE, hkv, d), jnp.int8),
+              _spec(head3, (num_pages, PAGE, hkv), jnp.float32))
+    pos_pool = _spec(rep, (num_pages, PAGE), jnp.int32)
+    bt, pos = _tables(rep, slots, n_logical)
     if step == "decode":
-        q = _spec(rep, (SLOTS, h, d), jnp.float32)
+        q = _spec(rep, (slots, h, d), jnp.float32)
 
         def fn(q, kq, kd, vq, vd, pp, bt, pos):
             return pa.paged_attn_decode_quant(
                 q, kq, kd, vq, vd, pp, bt, pos, mode="q8_0",
-                active_pages=N_LOGICAL, impl="pallas", interpret=False,
-                mesh=mesh2x2)
+                active_pages=n_logical, lane_pages=pos, impl="pallas",
+                interpret=False, mesh=mesh2x2)
     else:
         q = _spec(rep, (SLOTS, CHUNK, h, d), jnp.float32)
         pos = _spec(rep, (SLOTS, CHUNK), jnp.int32)
